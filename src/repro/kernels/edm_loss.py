@@ -44,15 +44,15 @@ def _loss_kernel(f_ref, z_ref, y_ref, cs_ref, co_ref, o_ref, *, rows: int,
     f = f_ref[0].astype(jnp.float32)
     z = z_ref[0].astype(jnp.float32)
     y = y_ref[0].astype(jnp.float32)
-    c_skip = cs_ref[0, 0]
-    c_out = co_ref[0, 0]
+    c_skip = cs_ref[0]                                   # (1, 1)
+    c_out = co_ref[0]
     target = (y - c_skip * z) / c_out
     err = jnp.square(f - target)
     # zero padded rows
     ridx = i * block_rows + jax.lax.broadcasted_iota(
         jnp.int32, err.shape, 0)
     err = jnp.where(ridx < rows, err, 0.0)
-    o_ref[0, 0] = jnp.sum(err)
+    o_ref[0, 0] = jnp.sum(err, keepdims=True)
 
 
 def _loss_bwd_kernel(f_ref, z_ref, y_ref, cs_ref, co_ref, g_ref,
@@ -63,9 +63,9 @@ def _loss_bwd_kernel(f_ref, z_ref, y_ref, cs_ref, co_ref, g_ref,
     f = f_ref[0].astype(jnp.float32)
     z = z_ref[0].astype(jnp.float32)
     y = y_ref[0].astype(jnp.float32)
-    c_skip = cs_ref[0, 0]
-    c_out = co_ref[0, 0]
-    g = g_ref[0, 0]                                      # tile cotangent
+    c_skip = cs_ref[0]                                   # (1, 1)
+    c_out = co_ref[0]
+    g = g_ref[0, 0]                                      # (1, 1) tile cotangent
     target = (y - c_skip * z) / c_out
     df = 2.0 * (f - target) * g
     ridx = i * block_rows + jax.lax.broadcasted_iota(jnp.int32, df.shape, 0)
@@ -79,14 +79,16 @@ def _partials_fwd_call(f, z, y, c_skip, c_out, rows, block_rows, interpret):
     B, _, d = f.shape
     fp, zp, yp = (_pad3(t, block_rows) for t in (f, z, y))
     ns = fp.shape[1] // block_rows
-    return pl.pallas_call(
+    partials = pl.pallas_call(
         functools.partial(_loss_kernel, rows=rows, block_rows=block_rows),
         grid=(B, ns),
         in_specs=[_rows_spec(block_rows, d)] * 3 + [_scalar_spec()] * 2,
         out_specs=_tile_spec(),
-        out_shape=jax.ShapeDtypeStruct((B, ns), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, ns, 1, 1), jnp.float32),
         interpret=interpret,
-    )(fp, zp, yp, c_skip, c_out)
+        name="edm_loss_fwd",
+    )(fp, zp, yp, c_skip.reshape(B, 1, 1), c_out.reshape(B, 1, 1))
+    return partials.reshape(B, ns)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -118,7 +120,9 @@ def _partials_vjp_bwd(sigma_data, block_rows, interpret, res, g):
                    jax.ShapeDtypeStruct(fp.shape, z.dtype),
                    jax.ShapeDtypeStruct(fp.shape, y.dtype)],
         interpret=interpret,
-    )(fp, zp, yp, c_skip, c_out, g.astype(jnp.float32))
+        name="edm_loss_bwd",
+    )(fp, zp, yp, c_skip.reshape(B, 1, 1), c_out.reshape(B, 1, 1),
+      g.astype(jnp.float32).reshape(B, ns, 1, 1))
     # σ parameterizes the sampled noise level — never differentiated
     return df[:, :S], dz[:, :S], dy[:, :S], jnp.zeros_like(sigma)
 

@@ -120,12 +120,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     mask = _tile_mask(qpos, kpos, cfg, seq_q, seq_k)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+    m_prev = m_ref[...]                            # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
@@ -133,13 +133,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     def _finalize():
         l = l_ref[...]
         o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+                       jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         # logsumexp per q row; fully-masked (padded) rows stay at ~NEG_INF
         lse_ref[0, 0] = m_ref[...] + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def _fwd_impl(q, k, v, cfg: FlashConfig) -> Tuple[jax.Array, jax.Array]:
-    """Returns (out (B,H,Sq,hd), lse (B,H,Sq_pad) float32)."""
+    """Returns (out (B,H,Sq,hd), lse (B,H,Sq_pad,1) float32).
+
+    Row statistics (lse, and m/l in scratch) carry a trailing unit axis:
+    Mosaic tiles the last two dims of every block by (8, 128) unless they
+    span the whole array axis, so a (block_q,) row vector must be laid out
+    as (block_q, 1)."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
     G = H // KV
@@ -169,19 +174,20 @@ def _fwd_impl(q, k, v, cfg: FlashConfig) -> Tuple[jax.Array, jax.Array]:
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(q.shape[:3], jnp.float32),
+            jax.ShapeDtypeStruct((*q.shape[:3], 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),    # m (running max)
-            pltpu.VMEM((block_q,), jnp.float32),    # l (running sum)
+            pltpu.VMEM((block_q, 1), jnp.float32),   # m (running max)
+            pltpu.VMEM((block_q, 1), jnp.float32),   # l (running sum)
             pltpu.VMEM((block_q, hd), jnp.float32),  # acc (weighted values)
         ],
         interpret=cfg.interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out[:, :, :Sq], lse
 
@@ -206,17 +212,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                            # (bq,)
-    delta = delta_ref[0, 0]                        # (bq,)
+    lse = lse_ref[0, 0]                            # (bq, 1)
+    delta = delta_ref[0, 0]                        # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     qpos, kpos = _tile_positions(iq, ik, cfg.block_q, cfg.block_k)
     mask = _tile_mask(qpos, kpos, cfg, seq_q, seq_k)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     acc_ref[...] += jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -248,12 +254,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             preferred_element_type=jnp.float32) * scale
     qpos, kpos = _tile_positions(iq, ik, cfg.block_q, cfg.block_k)
     mask = _tile_mask(qpos, kpos, cfg, seq_q, seq_k)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)     # (bq, bk)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)              # (bq, bk)
     dv_acc[...] += jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale                  # (bq, bk)
+    ds = p * (dp - delta) * scale                           # (bq, bk)
     dk_acc[...] += jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -278,13 +284,15 @@ def _bwd_impl(q, k, v, o, lse, do, cfg: FlashConfig):
     nq, nk = Sq_pad // block_q, Sk_pad // block_k
     # delta_i = sum_d dO_i · O_i — the softmax-normalization correction term
     # (one elementwise reduce; padded rows carry dO = 0 so contribute nothing)
-    delta = jnp.sum(dop.astype(jnp.float32) * op.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(dop.astype(jnp.float32) * op.astype(jnp.float32),
+                    axis=-1, keepdims=True)                 # (B, H, Sq_pad, 1)
 
     q_spec = pl.BlockSpec((1, 1, block_q, hd),
                           lambda b, h, iq, ik: (b, h, iq, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, hd),
                            lambda b, h, iq, ik: (b, h // G, ik, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq))
+    row_spec = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b, h, iq, ik: (b, h, iq, 0))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, cfg=cfg,
@@ -295,6 +303,7 @@ def _bwd_impl(q, k, v, o, lse, do, cfg: FlashConfig):
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=cfg.interpret,
+        name="flash_attention_bwd_dq",
     )(qp, kp, vp, dop, lse, delta)
 
     # dk/dv computed per q-head into (B, H, Sk, hd); GQA group-sum follows.
@@ -304,7 +313,8 @@ def _bwd_impl(q, k, v, o, lse, do, cfg: FlashConfig):
                             lambda b, h, ik, iq: (b, h // G, ik, 0))
     kvh_spec2 = pl.BlockSpec((1, 1, block_k, hd),
                              lambda b, h, ik, iq: (b, h, ik, 0))
-    row_spec2 = pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq))
+    row_spec2 = pl.BlockSpec((1, 1, block_q, 1),
+                             lambda b, h, ik, iq: (b, h, iq, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, cfg=cfg,
                           n_q_blocks=nq, seq_q=Sq, seq_k=Sk),
@@ -316,6 +326,7 @@ def _bwd_impl(q, k, v, o, lse, do, cfg: FlashConfig):
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
         interpret=cfg.interpret,
+        name="flash_attention_bwd_dkv",
     )(qp, kp, vp, dop, lse, delta)
 
     dq = dq[:, :, :Sq]

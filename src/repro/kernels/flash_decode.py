@@ -75,8 +75,8 @@ def _decode_kernel(*refs, scale: float, page_size: int,
     @pl.when(start < length)
     def _update():
         q = q_ref[0, 0].astype(jnp.float32)            # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (page_size, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (page_size, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
             # int8 bytes stream from HBM; dequant happens here in-register
             # with this PHYSICAL page's fp32 scale, scalar-prefetched like
@@ -91,12 +91,12 @@ def _decode_kernel(*refs, scale: float, page_size: int,
         if window is not None:
             valid &= idx > length - window
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        pexp = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_ref[...]                            # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             pexp, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -105,7 +105,7 @@ def _decode_kernel(*refs, scale: float, page_size: int,
     def _finalize():
         l = l_ref[...]
         o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+                       / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         # lse of the page partials; a slot with lengths[b]==0 finalizes at
         # ~NEG_INF so combine_self gives it zero weight.
         lse_ref[0, 0] = m_ref[...] + jnp.log(jnp.maximum(l, 1e-30))
@@ -120,7 +120,8 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Split-KV paged decode attention over committed tokens.
 
     q:          (B, KV, G, hd) — the single new token's grouped queries
-    k_pages/v_pages: (P, page_size, KV, hd) physical page pool
+    k_pages/v_pages: (P, KV, page_size, hd) physical page pool (KV-major:
+                one head's page is a whole (page_size, hd) tile)
     page_table: (B, n_logical_pages) int32 — physical page id per logical
                 page; entries past a sequence's allocation MUST still be
                 in-bounds (point them at a reserved page — see nn.cache)
@@ -135,7 +136,7 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     logsumexp. Fold in the current token's own k/v with ``combine_self``.
     """
     B, KV, G, hd = q.shape
-    psz = k_pages.shape[1]
+    psz = k_pages.shape[2]
     n_pages = page_table.shape[1]
     scale = 1.0 / (hd ** 0.5)
     quantized = k_scale is not None
@@ -150,27 +151,29 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # keep the unquantized specs verbatim so the bf16 program is unchanged
     if quantized:
         q_map = lambda b, kv, p, tbl, lens, ks, vs: (b, kv, 0, 0)
-        kv_map = lambda b, kv, p, tbl, lens, ks, vs: (tbl[b, p], 0, kv, 0)
-        lse_map = lambda b, kv, p, tbl, lens, ks, vs: (b, kv, 0)
+        kv_map = lambda b, kv, p, tbl, lens, ks, vs: (tbl[b, p], kv, 0, 0)
+        lse_map = lambda b, kv, p, tbl, lens, ks, vs: (b, kv, 0, 0)
     else:
         q_map = lambda b, kv, p, tbl, lens: (b, kv, 0, 0)
-        kv_map = lambda b, kv, p, tbl, lens: (tbl[b, p], 0, kv, 0)
-        lse_map = lambda b, kv, p, tbl, lens: (b, kv, 0)
+        kv_map = lambda b, kv, p, tbl, lens: (tbl[b, p], kv, 0, 0)
+        lse_map = lambda b, kv, p, tbl, lens: (b, kv, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quantized else 2,
         grid=(B, KV, n_pages),
         in_specs=[
             pl.BlockSpec((1, 1, Gp, hd), q_map),
-            pl.BlockSpec((1, psz, 1, hd), kv_map),
-            pl.BlockSpec((1, psz, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, psz, hd), kv_map),
+            pl.BlockSpec((1, 1, psz, hd), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, Gp, hd), q_map),
-            pl.BlockSpec((1, 1, Gp), lse_map),
+            pl.BlockSpec((1, 1, Gp, 1), lse_map),
         ],
+        # row statistics carry a trailing unit axis: Mosaic tiles the last
+        # two dims of a block, so a (Gp,) vector is laid out as (Gp, 1)
         scratch_shapes=[
-            pltpu.VMEM((Gp,), jnp.float32),      # m (running max)
-            pltpu.VMEM((Gp,), jnp.float32),      # l (running sum)
+            pltpu.VMEM((Gp, 1), jnp.float32),    # m (running max)
+            pltpu.VMEM((Gp, 1), jnp.float32),    # l (running sum)
             pltpu.VMEM((Gp, hd), jnp.float32),   # acc (weighted values)
         ],
     )
@@ -183,11 +186,12 @@ def flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, Gp, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, Gp), jnp.float32),
+            jax.ShapeDtypeStruct((B, KV, Gp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(*prefetch, q, k_pages, v_pages)
-    return out[:, :, :G], lse[:, :, :G]
+    return out[:, :, :G], lse[:, :, :G, 0]
 
 
 def combine_self(out: jax.Array, lse: jax.Array, s_self: jax.Array,

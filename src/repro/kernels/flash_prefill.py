@@ -77,8 +77,8 @@ def _prefill_kernel(*refs, scale: float, page_size: int,
     @pl.when(start < length + chunk)
     def _update():
         q = q_ref[0, 0].astype(jnp.float32)            # (rows, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (page_size, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (page_size, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
             # in-register dequant with this physical page's prefetched scale
             phys = table_ref[b, p]
@@ -94,12 +94,12 @@ def _prefill_kernel(*refs, scale: float, page_size: int,
         if window is not None:
             valid &= idx > qpos - window
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        pexp = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_ref[...]                            # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        pexp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(pexp, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             pexp, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -108,7 +108,7 @@ def _prefill_kernel(*refs, scale: float, page_size: int,
     def _finalize():
         l = l_ref[...]
         o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+                       / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -123,7 +123,8 @@ def flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                 occupies absolute positions [lengths[b], lengths[b] + C) and
                 its OWN k/v must already be appended to the pool
                 (``repro.nn.cache.append_paged_chunk``)
-    k_pages/v_pages: (P, page_size, KV, hd) physical page pool
+    k_pages/v_pages: (P, KV, page_size, hd) physical page pool (KV-major:
+                one head's page is a whole (page_size, hd) tile)
     page_table: (B, n_logical_pages) int32; entries past a sequence's
                 allocation MUST be in-bounds (reserved trash page — nn.cache)
     lengths:    (B,) int32 committed tokens per slot BEFORE this chunk
@@ -135,7 +136,7 @@ def flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     the chunk's self keys are in the pool, nothing left to fold in).
     """
     B, C, KV, G, hd = q.shape
-    psz = k_pages.shape[1]
+    psz = k_pages.shape[2]
     n_pages = page_table.shape[1]
     scale = 1.0 / (hd ** 0.5)
     quantized = k_scale is not None
@@ -153,24 +154,26 @@ def flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # keep the unquantized specs verbatim so the bf16 program is unchanged
     if quantized:
         q_map = lambda b, kv, p, tbl, lens, ks, vs: (b, kv, 0, 0)
-        kv_map = lambda b, kv, p, tbl, lens, ks, vs: (tbl[b, p], 0, kv, 0)
+        kv_map = lambda b, kv, p, tbl, lens, ks, vs: (tbl[b, p], kv, 0, 0)
     else:
         q_map = lambda b, kv, p, tbl, lens: (b, kv, 0, 0)
-        kv_map = lambda b, kv, p, tbl, lens: (tbl[b, p], 0, kv, 0)
+        kv_map = lambda b, kv, p, tbl, lens: (tbl[b, p], kv, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quantized else 2,
         grid=(B, KV, n_pages),
         in_specs=[
             pl.BlockSpec((1, 1, Rp, hd), q_map),
-            pl.BlockSpec((1, psz, 1, hd), kv_map),
-            pl.BlockSpec((1, psz, 1, hd), kv_map),
+            pl.BlockSpec((1, 1, psz, hd), kv_map),
+            pl.BlockSpec((1, 1, psz, hd), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, Rp, hd), q_map),
         ],
+        # row statistics carry a trailing unit axis: Mosaic tiles the last
+        # two dims of a block, so an (Rp,) vector is laid out as (Rp, 1)
         scratch_shapes=[
-            pltpu.VMEM((Rp,), jnp.float32),      # m (running max)
-            pltpu.VMEM((Rp,), jnp.float32),      # l (running sum)
+            pltpu.VMEM((Rp, 1), jnp.float32),    # m (running max)
+            pltpu.VMEM((Rp, 1), jnp.float32),    # l (running sum)
             pltpu.VMEM((Rp, hd), jnp.float32),   # acc (weighted values)
         ],
     )
@@ -183,5 +186,6 @@ def flash_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, KV, Rp, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_prefill",
     )(*prefetch, qr, k_pages, v_pages)
     return out[:, :, :rows].reshape(B, KV, C, G, hd).transpose(0, 2, 1, 3, 4)

@@ -66,8 +66,8 @@ def _ln_mod_bwd_kernel(x_ref, scale_ref, g_ref, dx_ref, dsc_ref, dsh_ref, *,
     dx = rstd * (dy - jnp.mean(dy, axis=-1, keepdims=True)
                  - xhat * jnp.mean(dy * xhat, axis=-1, keepdims=True))
     dx_ref[0] = dx.astype(dx_ref.dtype)
-    dsc_ref[0, 0] = jnp.sum(g * xhat, axis=0)
-    dsh_ref[0, 0] = jnp.sum(g, axis=0)
+    dsc_ref[0, 0] = jnp.sum(g * xhat, axis=0, keepdims=True)
+    dsh_ref[0, 0] = jnp.sum(g, axis=0, keepdims=True)
 
 
 def _ln_mod_fwd_call(x, scale, shift, eps, block_rows, interpret):
@@ -82,7 +82,8 @@ def _ln_mod_fwd_call(x, scale, shift, eps, block_rows, interpret):
         out_specs=_row_specs(block_rows, d),
         out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
         interpret=interpret,
-    )(xp, scale, shift)
+        name="fused_ln_modulate_fwd",
+    )(xp, scale.reshape(B, 1, d), shift.reshape(B, 1, d))
     return out[:, :S]
 
 
@@ -111,12 +112,13 @@ def _ln_mod_vjp_bwd(eps, block_rows, interpret, res, g):
         out_specs=[_row_specs(block_rows, d), _partial_spec(d),
                    _partial_spec(d)],
         out_shape=[jax.ShapeDtypeStruct(xp.shape, x.dtype),
-                   jax.ShapeDtypeStruct((B, ns, d), jnp.float32),
-                   jax.ShapeDtypeStruct((B, ns, d), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, ns, 1, d), jnp.float32),
+                   jax.ShapeDtypeStruct((B, ns, 1, d), jnp.float32)],
         interpret=interpret,
-    )(xp, scale, gp)
-    dscale = dsc.sum(axis=1).astype(scale.dtype)
-    dshift = dsh.sum(axis=1).astype(scale.dtype)
+        name="fused_ln_modulate_bwd",
+    )(xp, scale.reshape(B, 1, d), gp)
+    dscale = dsc.sum(axis=(1, 2)).astype(scale.dtype)
+    dshift = dsh.sum(axis=(1, 2)).astype(scale.dtype)
     return dx[:, :S], dscale, dshift
 
 
@@ -145,7 +147,7 @@ def _gate_res_bwd_kernel(br_ref, gate_ref, g_ref, dbr_ref, dg_ref):
     g = g_ref[0].astype(jnp.float32)
     dbr_ref[0] = (g * (1.0 + gate_ref[0].astype(jnp.float32))
                   ).astype(dbr_ref.dtype)
-    dg_ref[0, 0] = jnp.sum(g * br, axis=0)
+    dg_ref[0, 0] = jnp.sum(g * br, axis=0, keepdims=True)
 
 
 def _gate_res_fwd_call(res, branch, gate, block_rows, interpret):
@@ -162,7 +164,8 @@ def _gate_res_fwd_call(res, branch, gate, block_rows, interpret):
         out_specs=_row_specs(block_rows, d),
         out_shape=jax.ShapeDtypeStruct(rp.shape, res.dtype),
         interpret=interpret,
-    )(rp, bp, gate)
+        name="fused_gate_residual_fwd",
+    )(rp, bp, gate.reshape(B, 1, d))
     return out[:, :S]
 
 
@@ -190,10 +193,11 @@ def _gate_res_vjp_bwd(block_rows, interpret, res, g):
                   _row_specs(block_rows, d)],
         out_specs=[_row_specs(block_rows, d), _partial_spec(d)],
         out_shape=[jax.ShapeDtypeStruct(bp.shape, branch.dtype),
-                   jax.ShapeDtypeStruct((B, ns, d), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, ns, 1, d), jnp.float32)],
         interpret=interpret,
-    )(bp, gate, gp)
-    dgate = dg.sum(axis=1).astype(gate.dtype)
+        name="fused_gate_residual_bwd",
+    )(bp, gate.reshape(B, 1, d), gp)
+    dgate = dg.sum(axis=(1, 2)).astype(gate.dtype)
     return g, dbr[:, :S], dgate        # d res = identity pass-through
 
 
@@ -212,16 +216,16 @@ def fused_gate_residual(res: jax.Array, branch: jax.Array, gate: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _euler_kernel(z_ref, f_ref, a_ref, b_ref, o_ref):
-    a = a_ref[0, 0]                                       # scalars per example
-    b = b_ref[0, 0]
+    a = a_ref[0]                                  # (1, 1) scalars per example
+    b = b_ref[0]
     o_ref[0] = (a * z_ref[0].astype(jnp.float32)
                 + b * f_ref[0].astype(jnp.float32)).astype(o_ref.dtype)
 
 
 def _euler_bwd_kernel(g_ref, a_ref, b_ref, dz_ref, df_ref):
     g = g_ref[0].astype(jnp.float32)
-    dz_ref[0] = (a_ref[0, 0] * g).astype(dz_ref.dtype)
-    df_ref[0] = (b_ref[0, 0] * g).astype(df_ref.dtype)
+    dz_ref[0] = (a_ref[0] * g).astype(dz_ref.dtype)
+    df_ref[0] = (b_ref[0] * g).astype(df_ref.dtype)
 
 
 def _euler_coeffs(sigma, sigma_to, sigma_data: float):
@@ -253,7 +257,8 @@ def _euler_fwd_call(z, f, a, b, block_rows, interpret):
         out_specs=_row_specs(block_rows, d),
         out_shape=jax.ShapeDtypeStruct(zp.shape, z.dtype),
         interpret=interpret,
-    )(zp, fp, a, b)
+        name="fused_euler_fwd",
+    )(zp, fp, a.reshape(B, 1, 1), b.reshape(B, 1, 1))
     return out[:, :S]
 
 
@@ -283,7 +288,8 @@ def _euler_vjp_bwd(sigma_data, block_rows, interpret, res, g):
         out_shape=[jax.ShapeDtypeStruct(gp.shape, g.dtype),
                    jax.ShapeDtypeStruct(gp.shape, g.dtype)],
         interpret=interpret,
-    )(gp, a, b)
+        name="fused_euler_bwd",
+    )(gp, a.reshape(B, 1, 1), b.reshape(B, 1, 1))
     # σ is sampled noise-schedule data, never a learnable input — zero cotangent
     return dz[:, :S], df[:, :S], jnp.zeros_like(sigma), jnp.zeros_like(sigma_to)
 

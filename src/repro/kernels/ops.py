@@ -128,7 +128,7 @@ def flash_decode(q, k_pages, v_pages, page_table, lengths,
                  window: Optional[int] = None,
                  k_scale=None, v_scale=None):
     """Split-KV paged decode attention (flash-decoding). q: (B, KV, G, hd);
-    k/v pages: (P, page_size, KV, hd). For int8 pools pass the per-page fp32
+    k/v pages: (P, KV, page_size, hd). For int8 pools pass the per-page fp32
     ``k_scale``/``v_scale`` arrays — dequant is fused into the kernel.
     Returns (out, lse) fp32 partials over the committed tokens; fold in the
     current token's own k/v with ``flash_decode.combine_self``. This is the

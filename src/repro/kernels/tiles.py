@@ -1,6 +1,7 @@
 """Shared tile helpers for the Pallas kernels: sequence-axis zero-padding to
 a block multiple and the recurring BlockSpec shapes ((B, rows, d) row tiles,
-(B, d) per-example vectors, (B, 1) scalars, (B, ns, d) per-tile partials).
+(B, 1, d) per-example vectors, (B, 1, 1) scalars, (B, ns, 1, ·) per-tile
+partials).
 One definition so padding semantics cannot drift between kernels."""
 from __future__ import annotations
 
@@ -29,17 +30,27 @@ def row_spec(block_rows: int, d: int):
     return pl.BlockSpec((1, block_rows, d), lambda b, i: (b, i, 0))
 
 
+# Mosaic tiles the LAST TWO dims of every block by (8, 128) unless a dim
+# spans its whole array axis. Per-example vectors, scalars and per-tile
+# partials therefore carry unit axes that ARE whole array axes: a (B, d)
+# vector is passed as (B, 1, d), a (B,) scalar as (B, 1, 1), and (B, ns)
+# partials as (B, ns, 1, 1) / (B, ns, 1, d).
+
 def vec_spec(d: int):
-    return pl.BlockSpec((1, d), lambda b, i: (b, 0))
+    """(B, 1, d) per-example vector, broadcast over a row tile."""
+    return pl.BlockSpec((1, 1, d), lambda b, i: (b, 0, 0))
 
 
 def scalar_spec():
-    return pl.BlockSpec((1, 1), lambda b, i: (b, 0))
+    """(B, 1, 1) per-example scalar."""
+    return pl.BlockSpec((1, 1, 1), lambda b, i: (b, 0, 0))
 
 
 def tile_spec():
-    return pl.BlockSpec((1, 1), lambda b, i: (b, i))
+    """(B, ns, 1, 1) one scalar per row tile."""
+    return pl.BlockSpec((1, 1, 1, 1), lambda b, i: (b, i, 0, 0))
 
 
 def partial_spec(d: int):
-    return pl.BlockSpec((1, 1, d), lambda b, i: (b, i, 0))
+    """(B, ns, 1, d) one d-vector per row tile."""
+    return pl.BlockSpec((1, 1, 1, d), lambda b, i: (b, i, 0, 0))

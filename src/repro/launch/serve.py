@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import precision as precision_mod
+from repro import runtime
 from repro.configs import DBConfig, get_config, reduced
 from repro.core import DiffusionBlocksModel
 from repro.checkpoint import load_blocks
@@ -1493,6 +1494,7 @@ def main():
     ap.add_argument("--cond-pool", type=int, default=3,
                     help="distinct conditioning inputs in the pool")
     args = ap.parse_args()
+    runtime.init_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     n_units = DiffusionBlocksModel(cfg, DBConfig(num_blocks=1)).model.n_units

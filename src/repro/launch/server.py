@@ -56,6 +56,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import runtime
 from repro.launch.serve import (AdmissionError, ContinuousBatcher,
                                 PRIORITY_CLASSES, Request)
 
@@ -905,6 +906,7 @@ def main():
     ap.add_argument("--port", type=int, default=8080)
     add_server_args(ap)
     args = ap.parse_args()
+    runtime.init_compile_cache()
     try:
         asyncio.run(_serve_forever(args))
     except KeyboardInterrupt:

@@ -29,7 +29,7 @@ import time
 import jax
 import numpy as np
 
-from repro import configs
+from repro import configs, runtime
 from repro.configs import DBConfig, get_config, reduced
 from repro.configs.base import TrainConfig
 from repro.core import DiffusionBlocksModel
@@ -101,6 +101,7 @@ def main():
                     help="batches a dead pod stays down before its block is "
                          "re-adopted (--block-parallel pod_die)")
     args = ap.parse_args()
+    runtime.init_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -3,8 +3,10 @@
 Instead of one dense worst-case ``(B, C_max, KV, hd)`` slab per layer, keys
 and values live in a pool of fixed-size PAGES shared by every sequence slot:
 
-  pages      (P, page_size, KV, hd)   physical storage (bf16 under the
-                                      serving precision policy)
+  pages      (P, KV, page_size, hd)   physical storage (bf16 under the
+                                      serving precision policy); KV-major so
+                                      one head's page is a whole
+                                      (page_size, hd) tile for the kernels
   page_table (B, n_logical_pages)     int32 — physical page id backing
                                       logical page p of slot b
   lengths    (B,) int32               committed tokens per slot
@@ -56,7 +58,7 @@ class PagedKV:
     ``None`` — the unquantized pytree structure, and therefore every compiled
     program on the bf16 path, is byte-identical to the pre-quantization
     layout."""
-    k: jax.Array    # (P, page_size, KV, hd) — leading unit axes when stacked
+    k: jax.Array    # (P, KV, page_size, hd) — leading unit axes when stacked
     v: jax.Array
     k_scale: Optional[jax.Array] = None   # (P, 1, 1, 1) fp32, quantized only
     v_scale: Optional[jax.Array] = None
@@ -70,7 +72,7 @@ class PagedKV:
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[-3]
+        return self.k.shape[-2]
 
     @property
     def quantized(self) -> bool:
@@ -95,7 +97,7 @@ def is_quantized_dtype(dtype) -> bool:
 
 
 def quantize_pages(x: jax.Array, dtype=jnp.int8):
-    """Per-page symmetric absmax quantization. ``x`` is ``(..., psz, KV,
+    """Per-page symmetric absmax quantization. ``x`` is ``(..., KV, psz,
     hd)`` float pages (any number of leading page/unit axes); returns
     ``(q, scale)`` with ``q`` in ``dtype`` and ``scale`` fp32 shaped
     ``(..., 1, 1, 1)`` so ``dequantize_pages`` is a broadcast multiply.
@@ -117,7 +119,7 @@ def dequantize_pages(q: jax.Array, scale: jax.Array) -> jax.Array:
 def init_paged_kv(n_pages: int, page_size: int, dims: A.AttnDims,
                   dtype=jnp.bfloat16) -> PagedKV:
     dtype = resolve_kv_dtype(dtype)
-    shape = (n_pages, page_size, dims.n_kv_heads, dims.head_dim)
+    shape = (n_pages, dims.n_kv_heads, page_size, dims.head_dim)
     k, v = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
     if is_quantized_dtype(dtype):
         scale = jnp.zeros((n_pages, 1, 1, 1), KV_SCALE_DTYPE)
@@ -193,8 +195,8 @@ def append_paged(pkv: PagedKV, k_new: jax.Array, v_new: jax.Array,
         phys = jnp.where(active, phys, TRASH_PAGE)
     if not pkv.quantized:
         return PagedKV(
-            pkv.k.at[phys, slot].set(k_new.astype(pkv.k.dtype)),
-            pkv.v.at[phys, slot].set(v_new.astype(pkv.v.dtype)),
+            pkv.k.at[phys, :, slot].set(k_new.astype(pkv.k.dtype)),
+            pkv.v.at[phys, :, slot].set(v_new.astype(pkv.v.dtype)),
         )
     # Quantized pool: the page is the quantization granule, so the write is
     # read-modify-REQUANTIZE on the B touched pages. Positions past the new
@@ -204,11 +206,11 @@ def append_paged(pkv: PagedKV, k_new: jax.Array, v_new: jax.Array,
     # the garbage).
     B = k_new.shape[0]
     rows = jnp.arange(B)
-    keep = (jnp.arange(psz)[None, :] <= slot[:, None])[..., None, None]
+    keep = (jnp.arange(psz)[None, :] <= slot[:, None])[:, None, :, None]
 
     def one(pool, scale, new):
-        pg = dequantize_pages(pool[phys], scale[phys])    # (B, psz, KV, hd)
-        pg = pg.at[rows, slot].set(new.astype(jnp.float32))
+        pg = dequantize_pages(pool[phys], scale[phys])    # (B, KV, psz, hd)
+        pg = pg.at[rows, :, slot].set(new.astype(jnp.float32))
         q, s = quantize_pages(jnp.where(keep, pg, 0.0), pool.dtype)
         return pool.at[phys].set(q), scale.at[phys].set(s)
 
@@ -242,8 +244,8 @@ def append_paged_chunk(pkv: PagedKV, k_new: jax.Array, v_new: jax.Array,
         k_flat = k_new.reshape(B * C, *k_new.shape[2:])
         v_flat = v_new.reshape(B * C, *v_new.shape[2:])
         return PagedKV(
-            pkv.k.at[fp, fs].set(k_flat.astype(pkv.k.dtype)),
-            pkv.v.at[fp, fs].set(v_flat.astype(pkv.v.dtype)),
+            pkv.k.at[fp, :, fs].set(k_flat.astype(pkv.k.dtype)),
+            pkv.v.at[fp, :, fs].set(v_flat.astype(pkv.v.dtype)),
         )
     # Quantized pool: requantize every page the chunk touches. A C-token
     # chunk starting mid-page spans at most C // psz + 1 pages per slot;
@@ -269,13 +271,15 @@ def append_paged_chunk(pkv: PagedKV, k_new: jax.Array, v_new: jax.Array,
     fp = tphys.reshape(-1)
 
     def one(pool, scale, new):
-        pg = dequantize_pages(pool[tphys], scale[tphys])  # (B,npt,psz,KV,hd)
-        tail = pg.shape[3:]
-        flat = pg.reshape(B, span, *tail)
+        pg = dequantize_pages(pool[tphys], scale[tphys])  # (B,npt,KV,psz,hd)
+        KVh, hd = pg.shape[2], pg.shape[4]
+        # token-major view (B, span, KV, hd) for the splice, then back
+        flat = pg.transpose(0, 1, 3, 2, 4).reshape(B, span, KVh, hd)
         flat = flat.at[rows, rel].set(new.astype(jnp.float32))
         flat = jnp.where(keep, flat, 0.0)
-        q, s = quantize_pages(flat.reshape(B, npt, psz, *tail), pool.dtype)
-        return (pool.at[fp].set(q.reshape(B * npt, psz, *tail)),
+        pages = flat.reshape(B, npt, psz, KVh, hd).transpose(0, 1, 3, 2, 4)
+        q, s = quantize_pages(pages, pool.dtype)
+        return (pool.at[fp].set(q.reshape(B * npt, KVh, psz, hd)),
                 scale.at[fp].set(s.reshape(B * npt, 1, 1, 1)))
 
     k_p, k_s = one(pkv.k, pkv.k_scale, k_new)
@@ -284,7 +288,7 @@ def append_paged_chunk(pkv: PagedKV, k_new: jax.Array, v_new: jax.Array,
 
 
 # the page axis of a PagedKV leaf counted from the END: leaves are
-# (*units, P, psz, KV, hd) with a VARIABLE number of leading unit axes
+# (*units, P, KV, psz, hd) with a VARIABLE number of leading unit axes
 # (VLM stacks (n_units, k_self, P, ...)), so only trailing-axis indexing
 # names the page axis reliably.
 PAGE_AXIS = -4
@@ -298,7 +302,7 @@ def _page_index(ids):
 
 def copy_pool_pages(cache, src, dst):
     """Copy physical page ``src`` onto ``dst`` in every PagedKV leaf of a
-    model cache (leaves are (*units, P, psz, KV, hd) — the page table is
+    model cache (leaves are (*units, P, KV, psz, hd) — the page table is
     shared across units, so one physical id names the same page everywhere).
     Pages are addressed at ``PAGE_AXIS`` from the end: families stack a
     VARIABLE number of leading unit axes (VLM's self leaves carry an extra
@@ -335,8 +339,8 @@ def dense_to_paged(k: jax.Array, v: jax.Array, page_size: int
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     npg = (C + pad) // psz
-    pages = PagedKV(k.reshape(B * npg, psz, KV, hd),
-                    v.reshape(B * npg, psz, KV, hd))
+    pages = PagedKV(k.reshape(B * npg, psz, KV, hd).transpose(0, 2, 1, 3),
+                    v.reshape(B * npg, psz, KV, hd).transpose(0, 2, 1, 3))
     table = jnp.arange(B * npg, dtype=jnp.int32).reshape(B, npg)
     return pages, table
 
@@ -607,13 +611,13 @@ def _attend_pages_ref(qg, pkv: PagedKV, page_table, lengths, k_self, v_self,
     B, KV, G, hd = qg.shape
     npg, psz = page_table.shape[1], pkv.page_size
     L = npg * psz
-    kk = pkv.k[page_table].astype(jnp.float32)        # (B, npg, psz, KV, hd)
+    kk = pkv.k[page_table].astype(jnp.float32)        # (B, npg, KV, psz, hd)
     vv = pkv.v[page_table].astype(jnp.float32)
     if pkv.quantized:                 # per-page dequant: broadcast multiply
         kk = kk * pkv.k_scale[page_table]
         vv = vv * pkv.v_scale[page_table]
-    kk = kk.reshape(B, L, KV, hd).transpose(0, 2, 1, 3)   # (B, KV, L, hd)
-    vv = vv.reshape(B, L, KV, hd).transpose(0, 2, 1, 3)
+    kk = kk.transpose(0, 2, 1, 3, 4).reshape(B, KV, L, hd)
+    vv = vv.transpose(0, 2, 1, 3, 4).reshape(B, KV, L, hd)
     scale = 1.0 / (hd ** 0.5)
     qf = qg.astype(jnp.float32)
     s = jnp.einsum("bkgd,bksd->bkgs", qf, kk) * scale
@@ -696,13 +700,13 @@ def _attend_prefill_ref(qg, pkv: PagedKV, page_table, lengths,
     B, C, KV, G, hd = qg.shape
     npg, psz = page_table.shape[1], pkv.page_size
     L = npg * psz
-    kk = pkv.k[page_table].astype(jnp.float32)        # (B, npg, psz, KV, hd)
+    kk = pkv.k[page_table].astype(jnp.float32)        # (B, npg, KV, psz, hd)
     vv = pkv.v[page_table].astype(jnp.float32)
     if pkv.quantized:                 # per-page dequant: broadcast multiply
         kk = kk * pkv.k_scale[page_table]
         vv = vv * pkv.v_scale[page_table]
-    kk = kk.reshape(B, L, KV, hd).transpose(0, 2, 1, 3)   # (B, KV, L, hd)
-    vv = vv.reshape(B, L, KV, hd).transpose(0, 2, 1, 3)
+    kk = kk.transpose(0, 2, 1, 3, 4).reshape(B, KV, L, hd)
+    vv = vv.transpose(0, 2, 1, 3, 4).reshape(B, KV, L, hd)
     scale = 1.0 / (hd ** 0.5)
     qf = qg.astype(jnp.float32)
     s = jnp.einsum("bckgd,bksd->bkgcs", qf, kk) * scale   # (B,KV,G,C,L)

@@ -43,7 +43,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 
 from repro import precision as precision_mod
@@ -109,7 +108,6 @@ class BlockParallelTrainer:
         self.B = dbm.num_blocks
         self.u = uniform_block_size(dbm.ranges)
         self.guard = GuardConfig() if guard is None else guard
-        self.guard_ewma = jnp.full((self.B,), -1.0, jnp.float32)
         self.anomaly_streak = np.zeros(self.B, np.int64)
         self.anomalies = np.zeros(self.B, np.int64)
         self.last_ok = np.ones(self.B, bool)
@@ -133,6 +131,15 @@ class BlockParallelTrainer:
             sp = NamedSharding(self.mesh, rules.block_state_specs()["stacked"])
             self.qranges = jax.device_put(self.qranges, sp)
             self.block_ids = jax.device_put(self.block_ids, sp)
+        self._set_ewma(jnp.full((self.B,), -1.0, jnp.float32))
+
+    def _set_ewma(self, ewma):
+        """The loss EWMA enters every step placed as the step returns it, so
+        the second batch does not compile the step again."""
+        if self.mesh is not None:
+            ewma = jax.device_put(ewma, NamedSharding(
+                self.mesh, rules.block_state_specs()["stacked"]))
+        self.guard_ewma = ewma
 
     # ------------------------------------------------------------------
     def _build_step(self, jit: bool):
@@ -254,11 +261,11 @@ class BlockParallelTrainer:
         if self.mode == "shard_map":
             specs = rules.block_state_specs()
             sp, rp, tk = specs["stacked"], specs["replicated"], specs["tokens"]
-            fn = shard_map(local_update, mesh=self.mesh,
-                           in_specs=(sp, sp, rp, rp, tk, sp, sp, sp, sp, sp,
-                                     sp, rp),
-                           out_specs=(sp, sp, rp, rp, sp, sp, sp, sp),
-                           check_rep=False)
+            fn = jax.shard_map(local_update, mesh=self.mesh,
+                               in_specs=(sp, sp, rp, rp, tk, sp, sp, sp, sp,
+                                         sp, sp, rp),
+                               out_specs=(sp, sp, rp, rp, sp, sp, sp, sp),
+                               check_vma=False)
         return jax.jit(fn) if jit else fn
 
     # ------------------------------------------------------------------
@@ -333,7 +340,7 @@ class BlockParallelTrainer:
     def set_guard_state(self, gs: Optional[dict]) -> None:
         if not gs:
             return
-        self.guard_ewma = jnp.asarray(np.asarray(gs["ewma"], np.float32))
+        self._set_ewma(jnp.asarray(np.asarray(gs["ewma"], np.float32)))
         self.anomaly_streak = np.asarray(gs["streak"], np.int64)
         self.anomalies = np.asarray(gs["anomalies"], np.int64)
 

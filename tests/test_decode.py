@@ -199,7 +199,8 @@ def test_paged_commit_matches_prefill(dbm_params):
     # gather the paged pool back into logical (units, B, S, KV, hd)
     for paged, dense in ((kv, pre),):
         k_log = paged["k"] if isinstance(paged, dict) else paged.k
-        k_log = k_log[:, table]                    # (units, B, pps, psz, ...)
+        k_log = k_log[:, table]               # (units, B, pps, KV, psz, hd)
+        k_log = k_log.transpose(0, 1, 2, 4, 3, 5)
         k_log = k_log.reshape(k_log.shape[0], B, pps * psz,
                               *k_log.shape[4:])[:, :, :S0]
         np.testing.assert_allclose(np.asarray(k_log, np.float32),

@@ -98,8 +98,8 @@ def test_flash_decode_sweep(B, KV, G, hd, psz, npg, window, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     P = 1 + B * npg
     pool = KVC.PagedKV(
-        jax.random.normal(ks[0], (P, psz, KV, hd), dtype),
-        jax.random.normal(ks[1], (P, psz, KV, hd), dtype))
+        jax.random.normal(ks[0], (P, KV, psz, hd), dtype),
+        jax.random.normal(ks[1], (P, KV, psz, hd), dtype))
     table = KVC.identity_page_table(B, npg)
     # ragged: slot 0 empty, last slot full, middle arbitrary
     lens = np.linspace(0, npg * psz, B).astype(np.int32)
@@ -126,8 +126,8 @@ def test_flash_decode_trash_page_entries_inert():
     dims_kv, G, hd, psz, npg = 2, 2, 32, 4, 3
     P = 1 + npg
     k1, k2 = jax.random.split(jax.random.PRNGKey(1))
-    pool = KVC.PagedKV(jax.random.normal(k1, (P, psz, dims_kv, hd)),
-                       jax.random.normal(k2, (P, psz, dims_kv, hd)))
+    pool = KVC.PagedKV(jax.random.normal(k1, (P, dims_kv, psz, hd)),
+                       jax.random.normal(k2, (P, dims_kv, psz, hd)))
     # slot uses only its first page (length 3 < psz); rest point at trash
     table = jnp.asarray([[1, KVC.TRASH_PAGE, KVC.TRASH_PAGE]], jnp.int32)
     lengths = jnp.asarray([3], jnp.int32)
@@ -166,7 +166,7 @@ def test_quantize_roundtrip_error_bound(psz, KV, hd):
     to PAGE_AXIS, and an all-zero page round-trips exactly with scale 0."""
     rng = np.random.RandomState(0)
     P = 6
-    x = jnp.asarray(rng.randn(P, psz, KV, hd) *
+    x = jnp.asarray(rng.randn(P, KV, psz, hd) *
                     rng.uniform(0.1, 10.0, size=(P, 1, 1, 1)), jnp.float32)
     x = x.at[-1].set(0.0)                       # empty page
     q, s = KVC.quantize_pages(x)
@@ -184,8 +184,8 @@ def test_quantize_roundtrip_error_bound(psz, KV, hd):
 
 
 def _quantized_pool(rng, P, psz, KV, hd):
-    kf = jnp.asarray(rng.randn(P, psz, KV, hd), jnp.float32)
-    vf = jnp.asarray(rng.randn(P, psz, KV, hd), jnp.float32)
+    kf = jnp.asarray(rng.randn(P, KV, psz, hd), jnp.float32)
+    vf = jnp.asarray(rng.randn(P, KV, psz, hd), jnp.float32)
     qk, ks = KVC.quantize_pages(kf)
     qv, vs = KVC.quantize_pages(vf)
     return KVC.PagedKV(qk, qv, ks, vs)
