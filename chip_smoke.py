@@ -14,14 +14,16 @@ One chip, three phases in one process:
             ``launch/train.py``) with ``impl="kernels"``, ``precision="bf16"``
             on 8x1024 tokens: every block takes TRAIN_STEPS steps, all losses
             finite; the compiled step must hold Mosaic kernels
-            (``tpu_custom_call``); block 0's first loss must match the XLA
-            reference step (``impl="chunked"``) within LOSS_RTOL
+            (``tpu_custom_call``); block 0's first loss and grad norm must
+            match the XLA reference step (``impl="chunked"``) within
+            LOSS_RTOL and GRAD_RTOL
   serving   the trained parameters behind ``ContinuousBatcher`` with
             ``impl="kernels"`` (flash-prefill + flash-decode) answer 8 ragged
             requests (prompts 64-256 tokens, 32 new tokens each); the same
             requests through the reference route (``impl="auto"``) must agree:
-            next-token logits after prefill within LOGIT_TOL, greedy tokens in
-            the same context at a rate of at least TOKEN_AGREE
+            the engine's next-token logits after prefill within LOGIT_TOL,
+            greedy tokens identical in at least MIN_IDENTICAL requests and
+            none diverging before new token MIN_DIVERGE
 
 ``--chips 4`` runs only the block-parallel phase: ``BlockParallelTrainer`` on
 four chips (pod=4, data=1, shard_map) against the round-robin schedule on one
@@ -50,12 +52,19 @@ TRAIN_STEPS = 2                   # steps per block
 N_REQ, PROMPT_MIN, PROMPT_MAX, MAX_NEW = 8, 64, 256, 32
 PAGE, CHUNK, SEG = 16, 64, 16
 PAR_BATCHES = 3                   # --chips 4: batches per trainer
-# Tolerances (see CHANGES.md). bf16 keeps 8 mantissa bits (one ulp is 2^-8
-# = 0.4% relative); the kernel and reference routes differ only in how
-# attention / LayerNorm / gating round and sum, so they agree to a few ulps.
-LOSS_RTOL = 1e-2                  # scalar CE loss, averaged over 8k tokens
-LOGIT_TOL = 5e-2                  # max |Δlogit| / max |logit|, per request
-TOKEN_AGREE = 0.9                 # greedy top-1, same-context comparisons
+# Tolerances (see CHANGES.md), each with the reading of the first v5e run.
+# bf16 keeps 8 mantissa bits (one ulp is 2^-8 = 0.4% relative); the kernel
+# and reference routes differ only in how attention rounds and sums, and an
+# 8k-token mean averages that out.
+LOSS_RTOL = 1e-4                  # scalar CE loss (measured 1.23e-6)
+GRAD_RTOL = 1e-3                  # global grad norm (measured < 1e-5)
+LOGIT_TOL = 1e-2                  # max |dlogit| / max |logit| (measured 8.6e-4)
+# Greedy tokens: a near-tied argmax may flip on rounding and then the two
+# routes see different contexts, so at most N_REQ - MIN_IDENTICAL requests
+# may diverge, and none before token MIN_DIVERGE (measured: 7/8 identical,
+# the other first differs at token 27).
+MIN_IDENTICAL = N_REQ - 2
+MIN_DIVERGE = MAX_NEW // 4
 
 
 class SmokeFailure(RuntimeError):
@@ -75,6 +84,11 @@ def check_mosaic(text: str, what: str):
 
 def log(msg: str):
     print(msg, flush=True)
+
+
+def flops(compiled) -> float:
+    """The compiler's FLOP count for a compiled program."""
+    return float((compiled.cost_analysis() or {}).get("flops", float("nan")))
 
 
 def build_model(seed: int):
@@ -133,9 +147,16 @@ def phase_training(dbm, params, tokens, seed: int):
             t0 = time.perf_counter()
             ref_c = ref_step.lower(params, opt, tokens, rs).compile()
             t_ref_c = time.perf_counter() - t0
+            t0 = time.perf_counter()
             _, _, ref_loss, ref_m = jax.block_until_ready(
                 ref_c(params, opt, tokens, rs))
+            t_ref_run = time.perf_counter() - t0
             ref_loss, ref_gn = float(ref_loss), float(ref_m["grad_norm"])
+            log(f"[train] compiler FLOPs per block step: reference "
+                f"{flops(ref_c) / 1e12:.4f} TFLOP, kernels route "
+                f"{flops(compiled) / 1e12:.4f} TFLOP (Pallas calls carry "
+                f"no cost estimate, so they count 0) | reference step "
+                f"first run {t_ref_run:.4f}s")
         block_losses, t_run = [], []
         for s in range(TRAIN_STEPS):
             t0 = time.perf_counter()
@@ -154,12 +175,16 @@ def phase_training(dbm, params, tokens, seed: int):
               f"block {b}: non-finite loss {block_losses}")
         if b == 0:
             rel = abs(block_losses[0] - ref_loss) / abs(ref_loss)
+            grel = abs(ker_gn - ref_gn) / abs(ref_gn)
             log(f"[train] block 0 kernels vs reference (impl=chunked, "
                 f"compile {t_ref_c:.2f}s): loss {block_losses[0]:.6f} vs "
                 f"{ref_loss:.6f} (rel {rel:.2e}, limit {LOSS_RTOL:.0e}) | "
-                f"grad norm {ker_gn:.5f} vs {ref_gn:.5f}")
+                f"grad norm {ker_gn:.7f} vs {ref_gn:.7f} (rel {grel:.2e}, "
+                f"limit {GRAD_RTOL:.0e})")
             check(rel <= LOSS_RTOL, f"kernels loss differs from the "
                   f"reference by {rel:.3e} > {LOSS_RTOL}")
+            check(grel <= GRAD_RTOL, f"kernels grad norm differs from the "
+                  f"reference by {grel:.3e} > {GRAD_RTOL}")
         del opt
     log(f"[train] all {dbm.num_blocks} blocks trained, "
         f"{TRAIN_STEPS * dbm.num_blocks} steps, losses finite: "
@@ -200,59 +225,34 @@ def serve(dbm, params, prompts, impl: str, seed: int):
     return [list(r.out) for r in done], cb, dt
 
 
-def next_token_logits(dbm, cb, params, prompts, impl: str, seed: int):
-    """Prefill the requests through the batcher's own compiled chunk program,
-    then denoise the next token over the paged cache: logits (N, vocab)."""
-    import functools
-
+def next_token_logits(cb, params, prompts, impl: str, seed: int):
+    """The serving engine's next-token logits (N, vocab) after prefilling the
+    requests: ``DecodeEngine.next_token_logits``, the logits its decode
+    programs sample from. On the kernel route, the same call lowered as one
+    program must hold Mosaic kernels."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
-    from repro.nn import cache as KVC
-    eng = cb.eng
-    n, pps = len(prompts), KVC.pages_for(cb.max_len, PAGE)
-    kv = dbm.model.init_paged_cache(n, cb.total_pages, PAGE, eng.pol)
-    table = np.asarray(KVC.identity_page_table(n, pps))
-    buf = np.zeros((n, PROMPT_MAX), np.int32)
+    buf = np.zeros((len(prompts), PROMPT_MAX), np.int32)
     plens = np.array([len(p) for p in prompts], np.int32)
     for i, p in enumerate(prompts):
         buf[i, :len(p)] = p
-    args = (jnp.asarray(table), jnp.zeros((n,), jnp.int32), jnp.asarray(buf),
-            jnp.asarray(plens), jnp.zeros((n,), jnp.int32))
+    rng = jax.random.PRNGKey(seed + 4)
+
+    def call(params, buf, plens, rng):
+        return cb.eng.next_token_logits(params, buf, rng,
+                                        prompt_lengths=plens, page_size=PAGE)
+
     if impl == "kernels":
-        check_mosaic(eng._prefill_chunk1.lower(params, kv, *args).as_text(),
-                     "serving prefill chunk")
-    lengths = args[1]
-    for _ in range(-(-PROMPT_MAX // CHUNK)):
-        kv, lengths = eng._prefill_chunk1(params, kv, args[0], lengths,
-                                          *args[2:])
-    check(bool(np.all(np.asarray(lengths) == plens)), "prefill lengths")
-
-    @functools.partial(jax.jit, static_argnames="impl")
-    def logits_fn(params, kv, table, lengths, rng, impl):
-        ctx = dbm._paged_ctx(params, lengths, table, None, eng.pol, impl)
-        d = dbm.denoise_next_token(params, kv, None, rng, ctx, 1)
-        return dbm.model.logits(params, d)[:, 0].astype(jnp.float32)
-
-    lowered = logits_fn.lower(params, kv, args[0], lengths,
-                              jax.random.PRNGKey(seed + 4), impl=impl)
-    if impl == "kernels":
-        check_mosaic(lowered.as_text(), "serving decode")
-    return np.asarray(lowered.compile()(params, kv, args[0], lengths,
-                                        jax.random.PRNGKey(seed + 4)))
+        check_mosaic(jax.jit(call).lower(params, buf, plens, rng).as_text(),
+                     "serving prefill + next-token logits")
+    return np.asarray(call(params, buf, plens, rng))
 
 
-def same_context_agreement(ref, got):
-    """Greedy tokens compared where both routes saw the same context: per
-    request, positions up to and including the first divergence."""
-    compared = differ = 0
-    first = []
-    for a, b in zip(ref, got):
-        k = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y), None)
-        first.append(k)
-        compared += len(a) if k is None else k + 1
-        differ += k is not None
-    return 1.0 - differ / compared, compared, first
+def token_divergence(ref, got):
+    """Per request, the first new-token position where the greedy outputs
+    of the two routes differ (None where they are identical)."""
+    return [next((i for i, (x, y) in enumerate(zip(a, b)) if x != y), None)
+            for a, b in zip(ref, got)]
 
 
 def phase_serving(dbm, params, lm, seed: int):
@@ -266,13 +266,12 @@ def phase_serving(dbm, params, lm, seed: int):
         out, cb, t_cold = serve(dbm, params, prompts, impl, seed)
         out2, cb, t_warm = serve(dbm, params, prompts, impl, seed)
         check(out == out2, f"impl={impl}: a warm rerun changed the output")
-        n_tok = len(prompts) * MAX_NEW
         log(f"[serve] impl={impl}: cold run (compile + run) {t_cold:.2f}s, "
-            f"warm run {t_warm:.3f}s ({n_tok / t_warm:.1f} tok/s) | "
+            f"warm run {t_warm:.3f}s | "
             f"dispatches {cb.ingest_dispatches} ingest + "
             f"{cb.decode_dispatches} decode per run")
         t0 = time.perf_counter()
-        logits = next_token_logits(dbm, cb, params, prompts, impl, seed)
+        logits = next_token_logits(cb, params, prompts, impl, seed)
         log(f"[serve] impl={impl}: prefill + next-token logits "
             f"{time.perf_counter() - t0:.2f}s (incl. compile)")
         outs[impl] = (out, logits)
@@ -287,14 +286,16 @@ def phase_serving(dbm, params, lm, seed: int):
         f"{[float(f'{e:.2e}') for e in err]}, top-1 agreement {top1:.3f}")
     check(float(err.max()) <= LOGIT_TOL,
           f"kernel logits differ from the reference by {err.max():.3e}")
-    rate, compared, first = same_context_agreement(ref, ker)
-    exact = sum(a == b for a, b in zip(ref, ker))
+    first = token_divergence(ref, ker)
+    exact = first.count(None)
+    early = min((k for k in first if k is not None), default=None)
     log(f"[serve] greedy tokens kernels vs reference: {exact}/{len(ref)} "
-        f"requests identical, same-context agreement {rate:.4f} over "
-        f"{compared} comparisons (limit {TOKEN_AGREE}), first divergence "
-        f"per request {first}")
-    check(rate >= TOKEN_AGREE, f"same-context token agreement {rate:.4f} "
-          f"< {TOKEN_AGREE}")
+        f"requests identical (limit {MIN_IDENTICAL}), first divergence per "
+        f"request {first} (limit: none before token {MIN_DIVERGE})")
+    check(exact >= MIN_IDENTICAL, f"only {exact}/{len(ref)} requests "
+          f"generate identical tokens through both routes")
+    check(early is None or early >= MIN_DIVERGE, f"greedy tokens diverge "
+          f"at new token {early} < {MIN_DIVERGE}")
 
 
 def block_devices(trainer, state):

@@ -98,6 +98,8 @@ class DecodeEngine:
       _serve          continuous-batching segment: each active slot either
                       commits its next PROMPT token (per-token mode) or a
                       GENERATED token
+      _first_logits   the logits _decode / _serve sample a slot's next token
+                      from (``next_token_logits``)
     """
 
     def __init__(self, dbm: DiffusionBlocksModel, *, steps_per_block: int = 1,
@@ -168,6 +170,19 @@ class DecodeEngine:
                 body, (kv, lengths, rng), None, length=n)
             return kv, lengths, rng, toks.T          # (B, n)
 
+        def step_logits(params, kv, ctx, rng):
+            d = dbm.denoise_next_token(params, kv, None, rng, ctx, spb)
+            return dbm.model.logits(params, d)[:, 0]
+
+        def first_logits(params, kv, page_table, lengths, rng,
+                         cond_lengths):
+            # the rng splits of the first step of serve_scan / decode_scan
+            _, rs = jax.random.split(rng)
+            rn, _ = jax.random.split(rs)
+            ctx = dbm._paged_ctx(params, lengths, page_table, None, pol, impl,
+                                 cond_lengths)
+            return step_logits(params, kv, ctx, rn).astype(jnp.float32)
+
         def serve_scan(params, kv, page_table, lengths, prompt_buf, plens,
                        stop_at, active, rng, cond_lengths, n):
             def body(carry, _):
@@ -180,9 +195,8 @@ class DecodeEngine:
                 ctx = dbm._paged_ctx(params, lengths, page_table, act, pol,
                                      impl, cond_lengths)
                 rn, rsamp = jax.random.split(rs)
-                d = dbm.denoise_next_token(params, kv, None, rn, ctx, spb)
-                logits = dbm.model.logits(params, d)
-                gtok = dbm.sample_token(logits[:, 0], rsamp, temp, tk)
+                gtok = dbm.sample_token(step_logits(params, kv, ctx, rn),
+                                        rsamp, temp, tk)
                 tok = jnp.where(in_prompt, ptok, gtok)
                 kv = dbm.commit_token(params, kv, None, tok[:, None], ctx)
                 emitted = jnp.where(act & ~in_prompt, tok, -1)
@@ -198,6 +212,7 @@ class DecodeEngine:
                                        static_argnames=("n_chunks",))
         self._decode = jax.jit(decode_scan, static_argnames=("n",))
         self._serve = jax.jit(serve_scan, static_argnames=("n",))
+        self._first_logits = jax.jit(first_logits)
 
     # ------------------------------------------------------------------
     def run_prefill(self, params, kv, table, lengths, prompts, plens,
@@ -219,6 +234,32 @@ class DecodeEngine:
             self.prefill_steps += S0
         self.dispatches += 1
         return kv, lengths
+
+    def next_token_logits(self, params, prompts, rng=None, *,
+                          prompt_lengths=None,
+                          page_size: int = KVC.DEFAULT_PAGE_SIZE):
+        """fp32 logits (B, vocab) of each row's first generated token: the
+        prompts go through the configured prefill program, then through the
+        denoising chain that ``generate`` and the continuous batcher sample
+        from, with the same rng splits — at temperature 0 the argmax is the
+        first token ``generate(params, prompts, 1, rng)`` emits. Comparing
+        this across ``impl`` routes checks the served attention numerically.
+        Shapes are static, so the method also traces under ``jax.jit``."""
+        rng = rng if rng is not None else jax.random.PRNGKey(0)
+        prompts = jnp.asarray(prompts, jnp.int32)
+        B, S0 = prompts.shape
+        plens = (jnp.full((B,), S0, jnp.int32) if prompt_lengths is None
+                 else jnp.asarray(prompt_lengths, jnp.int32))
+        pps = KVC.pages_for(S0 + 1, page_size)
+        kv = self.dbm.model.init_paged_cache(B, 1 + B * pps, page_size,
+                                             self.pol)
+        table = KVC.identity_page_table(B, pps)
+        clens = jnp.zeros((B,), jnp.int32)
+        kv, lengths = self.run_prefill(params, kv, table,
+                                       jnp.zeros((B,), jnp.int32), prompts,
+                                       plens, clens)
+        self.dispatches += 1
+        return self._first_logits(params, kv, table, lengths, rng, clens)
 
     def generate(self, params, prompts, max_new: int, rng=None, *,
                  prompt_lengths=None, page_size: int = KVC.DEFAULT_PAGE_SIZE,

@@ -64,6 +64,33 @@ def test_scan_matches_reference_loop_ragged(dbm_params):
                                       np.asarray(prompts)[b, :pl])
 
 
+@pytest.mark.parametrize("impl", ["auto", "kernels"])
+def test_next_token_logits_match_first_generated_token(dbm_params, impl):
+    """next_token_logits exposes the logits the served programs sample from:
+    greedy argmax equals the first token generate emits, ragged prompts,
+    and the kernel route agrees with the reference route."""
+    dbm, params = dbm_params
+    prompts = jax.random.randint(jax.random.PRNGKey(4), (4, 8), 0,
+                                 TINY.vocab_size)
+    plens = np.array([3, 8, 5, 6])
+    eng = get_engine(dbm, precision="fp32", impl=impl)
+    rng = jax.random.PRNGKey(9)
+    logits = np.asarray(eng.next_token_logits(params, prompts, rng,
+                                              prompt_lengths=plens))
+    assert logits.shape == (4, TINY.vocab_size)
+    assert logits.dtype == np.float32 and np.isfinite(logits).all()
+    out = np.asarray(eng.generate(params, prompts, 1, rng,
+                                  prompt_lengths=plens))
+    np.testing.assert_array_equal(logits.argmax(-1),
+                                  out[np.arange(4), plens])
+    if impl == "kernels":
+        ref = get_engine(dbm, precision="fp32", impl="auto")
+        np.testing.assert_allclose(
+            logits, np.asarray(ref.next_token_logits(
+                params, prompts, rng, prompt_lengths=plens)),
+            rtol=1e-4, atol=1e-4)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", ["zamba2-7b", "xlstm-125m",
                                   "h2o-danube-3-4b"])
