@@ -1,0 +1,8 @@
+"""1 - device busy / traced window (union of op intervals; device trace)."""
+
+
+def read(run):
+    tr = run.trace
+    if tr is None or tr.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - tr.busy_s / tr.window_s)
