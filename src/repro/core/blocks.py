@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import precision as precision_mod
+from repro import tracing
 from repro.configs.base import HYBRID, SSM, DBConfig, ModelConfig
 from repro.core import edm
 from repro.core import partition as P
@@ -145,36 +146,55 @@ class DiffusionBlocksModel:
         cd = pol.compute_for(self.cfg.family)
         Bsz, S = tokens.shape
         start, size = unit_range if unit_range is not None else self.ranges[b]
-        r_sig, r_eps = jax.random.split(rng)
-        if sigma_qrange is not None:
-            q_lo, q_hi = sigma_qrange
-            sigma = edm.sample_sigma_in_qrange(r_sig, (Bsz, 1, 1), self.db,
-                                               q_lo, q_hi)
-        else:
-            sigma = self.sample_block_sigma(r_sig, (Bsz, 1, 1), b)
+        with tracing.scope(tracing.NOISE):
+            r_sig, r_eps = jax.random.split(rng)
+            if sigma_qrange is not None:
+                q_lo, q_hi = sigma_qrange
+                sigma = edm.sample_sigma_in_qrange(r_sig, (Bsz, 1, 1),
+                                                   self.db, q_lo, q_hi)
+            else:
+                sigma = self.sample_block_sigma(r_sig, (Bsz, 1, 1), b)
 
-        table = self.model.embedding_table(params)
-        emb_clean = table[tokens]
-        z, _ = edm.add_noise(r_eps, emb_clean.astype(jnp.float32), sigma)
-        c_skip, c_out, c_in, _ = edm.preconditioning(sigma, self.db.sigma_data)
-        z_in = (c_in * z).astype(cd)
+            table = self.model.embedding_table(params)
+            emb_clean = table[tokens]
+            z, _ = edm.add_noise(r_eps, emb_clean.astype(jnp.float32), sigma)
+            c_skip, c_out, c_in, _ = edm.preconditioning(sigma,
+                                                         self.db.sigma_data)
+            z_in = (c_in * z).astype(cd)
+            n = 2 * S if self.causal_mode == "concat" else S
+            ctx = self.make_ctx(params, n, "train", sigma, aux_inputs,
+                                impl=impl, precision=pol)
 
         if self.causal_mode == "concat":
-            stream = jnp.concatenate([emb_clean.astype(cd), z_in], axis=1)
-            ctx = self.make_ctx(params, 2 * S, "train", sigma, aux_inputs,
-                                impl=impl, precision=pol)
-            ctx.mask_mod = A.db_concat_mask(S)
-            ctx.rope_positions = jnp.concatenate(
-                [jnp.arange(S), jnp.arange(S)])
-            ctx.cond_mask = jnp.arange(2 * S) >= S
-            h, _, aux = self.model.apply_units(params, stream, start, size, ctx)
-            f_out = h[:, S:]
+            with tracing.scope(tracing.NOISE):
+                stream = jnp.concatenate([emb_clean.astype(cd), z_in], axis=1)
+                ctx.mask_mod = A.db_concat_mask(S)
+                ctx.rope_positions = jnp.concatenate(
+                    [jnp.arange(S), jnp.arange(S)])
+                ctx.cond_mask = jnp.arange(2 * S) >= S
+            with tracing.scope(tracing.LAYERS):
+                h, _, aux = self.model.apply_units(params, stream, start,
+                                                   size, ctx)
+                f_out = h[:, S:]
         else:
-            ctx = self.make_ctx(params, S, "train", sigma, aux_inputs,
-                                impl=impl, precision=pol)
-            _, f_out, aux = self.model.apply_units_two_pass(
-                params, emb_clean.astype(cd), z_in, start, size, ctx)
+            with tracing.scope(tracing.LAYERS):
+                _, f_out, aux = self.model.apply_units_two_pass(
+                    params, emb_clean.astype(cd), z_in, start, size, ctx)
 
+        loss, metrics = self._readout_loss(params, f_out, z, emb_clean,
+                                           sigma, tokens, impl)
+        metrics.update({"loss": loss, "aux": aux,
+                        "sigma_mean": jnp.mean(sigma)})
+        if self.cfg.moe is not None:
+            loss = loss + self.cfg.moe.router_aux_weight * aux
+        return loss, metrics
+
+    @tracing.scope(tracing.READOUT_CE)
+    def _readout_loss(self, params, f_out, z, emb_clean, sigma, tokens,
+                      impl):
+        """The block's loss from its output F: Eq. (6) in F-space (``l2``)
+        or CE through the readout of the denoised embedding."""
+        Bsz = tokens.shape[0]
         if self.db.loss == "l2":
             # Eq. (6) score matching in F-space (continuous targets): the
             # fused kernel never materializes the (y − c_skip z)/c_out target
@@ -188,18 +208,12 @@ class DiffusionBlocksModel:
                                      sigma_data=self.db.sigma_data)
             else:
                 loss = edm.edm_l2_loss(f32, z, y32, sigma, self.db.sigma_data)
-            metrics = {"l2": loss}
-        else:
-            d_hat = edm.denoise_combine(z, f_out.astype(jnp.float32), sigma,
-                                        self.db.sigma_data)
-            loss = chunked_ce(self.model, params,
-                              d_hat.astype(emb_clean.dtype), tokens)
-            metrics = {"ce": loss}
-        metrics.update({"loss": loss, "aux": aux,
-                        "sigma_mean": jnp.mean(sigma)})
-        if self.cfg.moe is not None:
-            loss = loss + self.cfg.moe.router_aux_weight * aux
-        return loss, metrics
+            return loss, {"l2": loss}
+        d_hat = edm.denoise_combine(z, f_out.astype(jnp.float32), sigma,
+                                    self.db.sigma_data)
+        loss = chunked_ce(self.model, params,
+                          d_hat.astype(emb_clean.dtype), tokens)
+        return loss, {"ce": loss}
 
     def e2e_loss(self, params, tokens, rng=None, aux_inputs=None,
                  impl: str = "auto", precision=None):
@@ -254,6 +268,7 @@ class DiffusionBlocksModel:
                                          sub_cache)
         return h
 
+    @tracing.scope(tracing.PROBE)
     def denoise_next_token(self, params, cache, pos, rng, ctx_base,
                            steps_per_block: int = 1) -> jax.Array:
         """Full Euler chain (σ_max → 0) for the token at ``pos`` (dense
@@ -281,6 +296,7 @@ class DiffusionBlocksModel:
             z = z.astype(f.dtype)
         return z
 
+    @tracing.scope(tracing.COMMIT)
     def commit_token(self, params, cache, pos, token, ctx_base):
         """Append the chosen clean token to every unit's cache in ONE scan.
 
@@ -317,6 +333,7 @@ class DiffusionBlocksModel:
             starts[self.ranges[b][0]] = True
         return jnp.asarray(starts)
 
+    @tracing.scope(tracing.SAMPLE)
     def sample_token(self, logits, rng, temperature: float = 0.0,
                      top_k: int = 0):
         """Greedy (``temperature == 0``) or temperature / top-k sampling.
@@ -331,6 +348,11 @@ class DiffusionBlocksModel:
             kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
             logits = jnp.where(logits < kth, -jnp.inf, logits)
         return jax.random.categorical(rng, logits)
+
+    @tracing.scope(tracing.SAMPLE)
+    def readout_logits(self, params, d_final):
+        """The readout of the denoised embedding, named with sampling."""
+        return self.model.logits(params, d_final)
 
     def serve_step(self, params, cache, pos, rng, aux_inputs=None,
                    steps_per_block: int = 1, temperature: float = 0.0,
@@ -349,7 +371,7 @@ class DiffusionBlocksModel:
         r_noise, r_samp = jax.random.split(rng)
         d_final = self.denoise_next_token(params, cache, pos, r_noise,
                                           ctx_base, steps_per_block)
-        logits = self.model.logits(params, d_final)
+        logits = self.readout_logits(params, d_final)
         token = self.sample_token(logits[:, 0], r_samp, temperature, top_k)
         new_cache = self.commit_token(params, cache, pos, token[:, None],
                                       ctx_base)
@@ -389,7 +411,7 @@ class DiffusionBlocksModel:
         r_noise, r_samp = jax.random.split(rng)
         d_final = self.denoise_next_token(params, kv, None, r_noise, ctx,
                                           steps_per_block)
-        logits = self.model.logits(params, d_final)
+        logits = self.readout_logits(params, d_final)
         token = self.sample_token(logits[:, 0], r_samp, temperature, top_k)
         new_kv = self.commit_token(params, kv, None, token[:, None], ctx)
         new_lengths = lengths + (active.astype(lengths.dtype)
@@ -409,6 +431,7 @@ class DiffusionBlocksModel:
                                  if active is not None else 1)
         return new_kv, new_lengths
 
+    @tracing.scope(tracing.COMMIT)
     def commit_prompt_chunk(self, params, kv, page_table, lengths, tokens, *,
                             n_valid, precision=None, impl: str = "auto",
                             cond_lengths=None):

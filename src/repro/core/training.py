@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import precision as precision_mod
+from repro import tracing
 from repro.configs.base import TrainConfig
 from repro.core.blocks import DiffusionBlocksModel
 from repro.optim import adamw, apply_updates, warmup_cosine
@@ -56,6 +57,7 @@ class GuardConfig:
         return ok, new_ewma
 
 
+@tracing.scope(tracing.BLOCK_VIEW)
 def extract_block_view(params: Dict, start: int, size: int) -> Dict:
     """Sub-tree containing ONLY block b's unit slice + shared periphery.
     The view is itself a valid params dict whose stacks have length ``size``
@@ -71,6 +73,7 @@ def extract_block_view(params: Dict, start: int, size: int) -> Dict:
     return view
 
 
+@tracing.scope(tracing.BLOCK_VIEW)
 def write_back_block_view(params: Dict, view: Dict, start: int) -> Dict:
     out = {}
     for k, v in params.items():
@@ -131,8 +134,9 @@ def make_db_train_step(dbm: DiffusionBlocksModel, b: int, tcfg: TrainConfig,
         view = extract_block_view(params, start, size)
 
         def loss_fn(v):
-            vc = precision_mod.cast_params_for_compute(pol, v,
-                                                       dbm.cfg.family)
+            with tracing.scope(tracing.BLOCK_VIEW):
+                vc = precision_mod.cast_params_for_compute(pol, v,
+                                                           dbm.cfg.family)
             loss, metrics = dbm.block_loss(vc, b, tokens, rng,
                                            aux_inputs=aux_inputs,
                                            impl=impl, unit_range=(0, size),
@@ -147,8 +151,9 @@ def make_db_train_step(dbm: DiffusionBlocksModel, b: int, tcfg: TrainConfig,
 
     def step(params, opt_state, tokens, rng, aux_inputs=None):
         view, loss, metrics, grads = grads_of(params, tokens, rng, aux_inputs)
-        updates, opt_state, om = opt_update(grads, opt_state, view)
-        view = apply_updates(view, updates)
+        with tracing.scope(tracing.OPTIMIZER):
+            updates, opt_state, om = opt_update(grads, opt_state, view)
+            view = apply_updates(view, updates)
         params = write_back_block_view(params, view, start)
         metrics = {**metrics, **om}
         return params, opt_state, loss, metrics
@@ -157,12 +162,14 @@ def make_db_train_step(dbm: DiffusionBlocksModel, b: int, tcfg: TrainConfig,
                      loss_mult=1.0):
         view, loss, metrics, grads = grads_of(params, tokens, rng,
                                               aux_inputs, loss_mult)
-        updates, opt2, om = opt_update(grads, opt_state, view)
-        view2 = apply_updates(view, updates)
-        ok, ewma = guard.classify(loss, om["grad_norm"], ewma)
-        sel = lambda new, old: jnp.where(ok, new, old)  # noqa: E731
-        view = jax.tree_util.tree_map(sel, view2, view)
-        opt_state = jax.tree_util.tree_map(sel, opt2, opt_state)
+        with tracing.scope(tracing.OPTIMIZER):
+            updates, opt2, om = opt_update(grads, opt_state, view)
+            view2 = apply_updates(view, updates)
+        with tracing.scope(tracing.GUARD):
+            ok, ewma = guard.classify(loss, om["grad_norm"], ewma)
+            sel = lambda new, old: jnp.where(ok, new, old)  # noqa: E731
+            view = jax.tree_util.tree_map(sel, view2, view)
+            opt_state = jax.tree_util.tree_map(sel, opt2, opt_state)
         params = write_back_block_view(params, view, start)
         metrics = {**metrics, **om, "ok": ok}
         return params, opt_state, ewma, loss, metrics
@@ -191,8 +198,9 @@ def make_e2e_train_step(dbm: DiffusionBlocksModel, tcfg: TrainConfig,
             loss_fn = jax.checkpoint(loss_fn)
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
-        updates, opt_state, om = opt_update(grads, opt_state, params)
-        params = apply_updates(params, updates)
+        with tracing.scope(tracing.OPTIMIZER):
+            updates, opt_state, om = opt_update(grads, opt_state, params)
+            params = apply_updates(params, updates)
         return params, opt_state, loss, {**metrics, **om}
 
     if jit:
@@ -239,16 +247,19 @@ def train_db(dbm: DiffusionBlocksModel, tcfg: TrainConfig, data_iter,
         opt_states.append(init_opt(params))
     history = []
     for it in range(tcfg.steps):
-        tokens = next(data_iter)
-        aux = aux_fn(tokens) if aux_fn else None
-        rng, rb, rs = jax.random.split(rng, 3)
-        b = int(jax.random.randint(rb, (), 0, dbm.num_blocks))
-        params, opt_states[b], loss, m = steppers[b](
-            params, opt_states[b], tokens, rs, aux)
-        history.append((it, b, float(loss)))
-        if tcfg.log_every and it % tcfg.log_every == 0:
-            log(f"[db] it={it} block={b} loss={float(loss):.4f} "
-                f"gn={float(m['grad_norm']):.2f}")
+        with tracing.span(tracing.BATCH):
+            tokens = next(data_iter)
+            aux = aux_fn(tokens) if aux_fn else None
+            rng, rb, rs = jax.random.split(rng, 3)
+            b = int(jax.random.randint(rb, (), 0, dbm.num_blocks))
+        with tracing.span(tracing.DISPATCH):
+            params, opt_states[b], loss, m = steppers[b](
+                params, opt_states[b], tokens, rs, aux)
+        with tracing.span(tracing.LOSS_READBACK):
+            history.append((it, b, float(loss)))
+            if tcfg.log_every and it % tcfg.log_every == 0:
+                log(f"[db] it={it} block={b} loss={float(loss):.4f} "
+                    f"gn={float(m['grad_norm']):.2f}")
     return params, history
 
 

@@ -64,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import precision as precision_mod
-from repro import runtime
+from repro import runtime, tracing
 from repro.configs import DBConfig, get_config, reduced
 from repro.core import DiffusionBlocksModel
 from repro.checkpoint import load_blocks
@@ -172,7 +172,7 @@ class DecodeEngine:
 
         def step_logits(params, kv, ctx, rng):
             d = dbm.denoise_next_token(params, kv, None, rng, ctx, spb)
-            return dbm.model.logits(params, d)[:, 0]
+            return dbm.readout_logits(params, d)[:, 0]
 
         def first_logits(params, kv, page_table, lengths, rng,
                          cond_lengths):
@@ -837,17 +837,18 @@ class ContinuousBatcher:
         """Give ``slot`` a private copy of its ``logical``-th page (the page
         is shared / cache-retained and about to be written). Returns False
         when no page could be allocated."""
-        src = int(self.table[slot, logical])
-        dst = self._alloc_page()
-        if dst is None:
-            return False
-        self.kv = KVC.copy_pool_pages(self.kv, src, dst)
-        self.cow_copies += 1
-        self.table[slot, logical] = dst
-        req = self.slot_req[slot]
-        req.pages[logical] = dst
-        self._release_pages([src])   # drop this slot's ref on the shared page
-        return True
+        with tracing.span(tracing.COW):
+            src = int(self.table[slot, logical])
+            dst = self._alloc_page()
+            if dst is None:
+                return False
+            self.kv = KVC.copy_pool_pages(self.kv, src, dst)
+            self.cow_copies += 1
+            self.table[slot, logical] = dst
+            req = self.slot_req[slot]
+            req.pages[logical] = dst
+            self._release_pages([src])   # drop this slot's ref on the page
+            return True
 
     def _make_writable(self, slot: int, lo: int, hi: int) -> bool:
         """Copy-on-write every shared page overlapping token positions
@@ -1401,7 +1402,9 @@ class ContinuousBatcher:
         finished.extend(self._enforce_deadlines())
         if not (self.queue or self.active.any()):
             return rng, finished
-        if not self._admit() and not self.active.any():
+        with tracing.span(tracing.ADMIT):
+            admitted = self._admit()
+        if not admitted and not self.active.any():
             # nothing running and nothing admitted: IMPOSSIBLE only when the
             # head request needs more pages than the pool can ever hold — a
             # transient allocator refusal (fault injection, racing eviction)
@@ -1470,7 +1473,8 @@ class ContinuousBatcher:
             self._collect(np.asarray(emitted))         # (slots, seg)
             if not self.chunked:
                 self._register_prefixes()
-        finished.extend(self._retire())
+        with tracing.span(tracing.RETIRE):
+            finished.extend(self._retire())
         return rng, finished
 
     def run(self, rng=None) -> List[Request]:

@@ -27,7 +27,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import runtime
+from repro import runtime, tracing
 from repro.configs.base import ModelConfig
 from repro.nn import layers as L
 from repro.nn import attention as A
@@ -234,12 +234,33 @@ def tlayer_apply(params, h, ctx: LayerCtx, *, cross: bool = False,
                  cache=None):
     """Returns (h, new_cache, aux_loss)."""
     cfg = ctx.cfg
-    dims = ctx.dims()
-    s1, c1, g1, s2, c2, g2 = _mods(params, ctx)
     aux = jnp.zeros((), jnp.float32)
     cm = ctx.cond_mask
+    with tracing.scope(tracing.ADALN):
+        s1, c1, g1, s2, c2, g2 = _mods(params, ctx)
+        x = _norm_modulate(params["ln1"], h, ctx, s1, c1, cm)
+    with tracing.scope(tracing.ATTN):
+        attn_out, new_cache = _tlayer_attention(params, x, ctx, cross,
+                                                bidirectional, cache)
+    with tracing.scope(tracing.ADALN):
+        h = adaln.gate(h, attn_out, g1, cm, impl=ctx.impl)
+        x = _norm_modulate(params["ln2"], h, ctx, s2, c2, cm)
+    with tracing.scope(tracing.MLP):
+        if moe_layer:
+            mlp_out, aux = moe_fwd(params["moe"], x, cfg.moe, cfg.mlp)
+        else:
+            mlp_out = L.apply_mlp(params["mlp"], x, cfg.mlp)
+    with tracing.scope(tracing.ADALN):
+        h = adaln.gate(h, mlp_out, g2, cm, impl=ctx.impl)
+    return h, new_cache, aux
 
-    x = _norm_modulate(params["ln1"], h, ctx, s1, c1, cm)
+
+def _tlayer_attention(params, x, ctx: LayerCtx, cross: bool,
+                      bidirectional: bool, cache):
+    """The attention half of ``tlayer_apply`` on the normed input ``x``:
+    (attention output, new cache) for the layer's mode."""
+    cfg = ctx.cfg
+    dims = ctx.dims()
     if ctx.mode in ("decode", "prefill_chunk") and not cross:
         if isinstance(cache, KVC.PagedKV):
             if ctx.mode == "prefill_chunk":
@@ -289,15 +310,7 @@ def tlayer_apply(params, h, ctx: LayerCtx, *, cross: bool = False,
             mask_mod=mask_mod, rope_positions=ctx.rope_positions,
             impl=ctx.impl, q_chunk=ctx.q_chunk, kv_chunk=ctx.kv_chunk)
         new_cache = {"k": k, "v": v} if ctx.mode == "prefill" else None
-    h = adaln.gate(h, attn_out, g1, cm, impl=ctx.impl)
-
-    x = _norm_modulate(params["ln2"], h, ctx, s2, c2, cm)
-    if moe_layer:
-        mlp_out, aux = moe_fwd(params["moe"], x, cfg.moe, cfg.mlp)
-    else:
-        mlp_out = L.apply_mlp(params["mlp"], x, cfg.mlp)
-    h = adaln.gate(h, mlp_out, g2, cm, impl=ctx.impl)
-    return h, new_cache, aux
+    return attn_out, new_cache
 
 
 def two_pass_mask(seq_len: int):

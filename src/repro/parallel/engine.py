@@ -46,6 +46,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro import precision as precision_mod
+from repro import tracing
 from repro.configs.base import TrainConfig
 from repro.core import partition as P
 from repro.core.blocks import DiffusionBlocksModel
@@ -160,8 +161,9 @@ class BlockParallelTrainer:
                 rng = jax.random.fold_in(rng, jax.lax.axis_index(data_ax))
 
             def loss_fn(v):
-                vc = precision_mod.cast_params_for_compute(pol, v,
-                                                           dbm.cfg.family)
+                with tracing.scope(tracing.BLOCK_VIEW):
+                    vc = precision_mod.cast_params_for_compute(
+                        pol, v, dbm.cfg.family)
                 loss, metrics = dbm.block_loss(vc, 0, tokens, rng, impl=impl,
                                                unit_range=(0, u),
                                                sigma_qrange=(q_lo, q_hi),
@@ -175,10 +177,11 @@ class BlockParallelTrainer:
             if data_ax is not None:
                 grads = jax.lax.pmean(grads, data_ax)
                 loss = jax.lax.pmean(loss, data_ax)
-            if tcfg.grad_clip is not None:
-                grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-            else:
-                gnorm = global_norm(grads)
+            with tracing.scope(tracing.OPTIMIZER):
+                if tcfg.grad_clip is not None:
+                    grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+                else:
+                    gnorm = global_norm(grads)
             return loss, grads, gnorm
 
         def local_update(stacks, stack_opt, periph, periph_opt, tokens,
@@ -198,25 +201,30 @@ class BlockParallelTrainer:
                 view = {**periph, **stack_b}
                 loss, grads, gnorm = block_grads(view, tokens, rng_b,
                                                  qr_b[0], qr_b[1], mult_b)
-                ok, ewma_b = guard.classify(loss, gnorm, ewma_b, act_b > 0)
+                with tracing.scope(tracing.GUARD):
+                    ok, ewma_b = guard.classify(loss, gnorm, ewma_b,
+                                                act_b > 0)
                 g_stack = {k: grads[k] for k in stack_b}
                 g_per = {k: grads[k] for k in periph}
-                if policy == "owner-broadcast":
-                    w = (bid == B - 1).astype(jnp.float32)
-                else:
-                    w = jnp.float32(1.0 / B)
-                w = jnp.where(ok, w, 0.0)
-                acc_g, acc_n, acc_w = acc
-                acc_g = jax.tree_util.tree_map(
-                    lambda a, g: a + w * jnp.where(ok, g.astype(jnp.float32),
-                                                   0.0), acc_g, g_per)
-                acc_n = acc_n + ok.astype(jnp.int32)
-                acc_w = acc_w + w
-                updates, opt_b2, _ = opt_update(g_stack, opt_b, stack_b)
-                stack_b2 = apply_updates(stack_b, updates)
-                sel = lambda new, old: jnp.where(ok, new, old)  # noqa: E731
-                stack_b = jax.tree_util.tree_map(sel, stack_b2, stack_b)
-                opt_b = jax.tree_util.tree_map(sel, opt_b2, opt_b)
+                with tracing.scope(tracing.PSUM):
+                    if policy == "owner-broadcast":
+                        w = (bid == B - 1).astype(jnp.float32)
+                    else:
+                        w = jnp.float32(1.0 / B)
+                    w = jnp.where(ok, w, 0.0)
+                    acc_g, acc_n, acc_w = acc
+                    acc_g = jax.tree_util.tree_map(
+                        lambda a, g: a + w * jnp.where(
+                            ok, g.astype(jnp.float32), 0.0), acc_g, g_per)
+                    acc_n = acc_n + ok.astype(jnp.int32)
+                    acc_w = acc_w + w
+                with tracing.scope(tracing.OPTIMIZER):
+                    updates, opt_b2, _ = opt_update(g_stack, opt_b, stack_b)
+                    stack_b2 = apply_updates(stack_b, updates)
+                with tracing.scope(tracing.GUARD):
+                    sel = lambda new, old: jnp.where(ok, new, old)  # noqa: E731
+                    stack_b = jax.tree_util.tree_map(sel, stack_b2, stack_b)
+                    opt_b = jax.tree_util.tree_map(sel, opt_b2, opt_b)
                 return (acc_g, acc_n, acc_w), (stack_b, opt_b, loss, gnorm,
                                                ok, ewma_b)
 
@@ -227,33 +235,39 @@ class BlockParallelTrainer:
                 jax.lax.scan(body, acc0, (stacks, stack_opt, rngs, qranges,
                                           block_ids, loss_mult, active, ewma))
             acc_g, acc_n, acc_w = acc
-            if pod_ax is not None:
-                acc_g = jax.lax.psum(acc_g, pod_ax)
-                acc_n = jax.lax.psum(acc_n, pod_ax)
-                acc_w = jax.lax.psum(acc_w, pod_ax)
-            # renormalize the periphery mean over the SURVIVING blocks. In
-            # the owner policy acc_g already carries exactly the owner's
-            # grads (w ∈ {0,1}), so the scale stays 1; in the mean policies
-            # B/n_ok re-weights the (1/B)Σ_ok sum to a true mean — exactly
-            # 1.0 when every block is clean (bit-parity with the old path).
-            if policy == "owner-broadcast":
-                scale = jnp.float32(1.0)
-                per_ok = acc_w > 0
-            else:
-                scale = B / jnp.maximum(acc_n.astype(jnp.float32), 1.0)
-                per_ok = acc_n > 0
-            g_per = jax.tree_util.tree_map(lambda a: a * scale, acc_g)
-            updates, new_popt, _ = popt_update(g_per, periph_opt, periph)
-            new_periph = apply_updates(periph, updates)
-            do_per = per_ok & upd_periph
-            sel_p = lambda new, old: jnp.where(do_per, new, old)  # noqa: E731
-            new_periph = jax.tree_util.tree_map(sel_p, new_periph, periph)
-            new_popt = jax.tree_util.tree_map(sel_p, new_popt, periph_opt)
-            if policy == "freeze-after-warmup":
-                frozen = periph_opt.step >= freeze_steps
-                keep = lambda old, new: jnp.where(frozen, old, new)  # noqa: E731
-                new_periph = jax.tree_util.tree_map(keep, periph, new_periph)
-                new_popt = jax.tree_util.tree_map(keep, periph_opt, new_popt)
+            with tracing.scope(tracing.PSUM):
+                if pod_ax is not None:
+                    acc_g = jax.lax.psum(acc_g, pod_ax)
+                    acc_n = jax.lax.psum(acc_n, pod_ax)
+                    acc_w = jax.lax.psum(acc_w, pod_ax)
+                # renormalize the periphery mean over the SURVIVING blocks.
+                # In the owner policy acc_g already carries exactly the
+                # owner's grads (w ∈ {0,1}), so the scale stays 1; in the
+                # mean policies B/n_ok re-weights the (1/B)Σ_ok sum to a
+                # true mean — exactly 1.0 when every block is clean
+                # (bit-parity with the old path).
+                if policy == "owner-broadcast":
+                    scale = jnp.float32(1.0)
+                    per_ok = acc_w > 0
+                else:
+                    scale = B / jnp.maximum(acc_n.astype(jnp.float32), 1.0)
+                    per_ok = acc_n > 0
+                g_per = jax.tree_util.tree_map(lambda a: a * scale, acc_g)
+            with tracing.scope(tracing.OPTIMIZER):
+                updates, new_popt, _ = popt_update(g_per, periph_opt, periph)
+                new_periph = apply_updates(periph, updates)
+            with tracing.scope(tracing.GUARD):
+                do_per = per_ok & upd_periph
+                sel_p = lambda new, old: jnp.where(do_per, new, old)  # noqa: E731
+                new_periph = jax.tree_util.tree_map(sel_p, new_periph, periph)
+                new_popt = jax.tree_util.tree_map(sel_p, new_popt, periph_opt)
+                if policy == "freeze-after-warmup":
+                    frozen = periph_opt.step >= freeze_steps
+                    keep = lambda old, new: jnp.where(frozen, old, new)  # noqa: E731
+                    new_periph = jax.tree_util.tree_map(keep, periph,
+                                                        new_periph)
+                    new_popt = jax.tree_util.tree_map(keep, periph_opt,
+                                                      new_popt)
             return (stacks, stack_opt, new_periph, new_popt, losses, gnorms,
                     oks, ewma)
 
@@ -302,31 +316,35 @@ class BlockParallelTrainer:
         blocks that actually ran are counted), and the per-block loss EWMA
         ``guard_ewma`` advances only on clean steps."""
         B = self.B
-        loss_mult = (jnp.ones((B,), jnp.float32) if loss_mult is None
-                     else jnp.asarray(loss_mult, jnp.float32))
-        active = (jnp.ones((B,), jnp.float32) if active is None
-                  else jnp.asarray(active, jnp.float32))
-        if self.mesh is not None:
-            specs = rules.block_state_specs()
-            tokens = jax.device_put(
-                tokens, NamedSharding(self.mesh, specs["tokens"]))
-            sp = NamedSharding(self.mesh, specs["stacked"])
-            loss_mult = jax.device_put(loss_mult, sp)
-            active = jax.device_put(active, sp)
-        (stacks, stack_opt, periph, periph_opt, losses, gnorms, oks,
-         ewma) = self._step_fn(
-            state.stacks, state.stack_opt, state.periph, state.periph_opt,
-            tokens, rngs, self.qranges, self.block_ids, loss_mult, active,
-            self.guard_ewma, jnp.asarray(bool(update_periphery)))
-        self.guard_ewma = ewma
-        oks_np = np.asarray(oks).astype(bool)
-        ran = np.asarray(active) > 0
-        bad = ran & ~oks_np
-        self.last_ok = oks_np | ~ran
-        self.anomalies += bad
-        self.anomaly_streak = np.where(
-            bad, self.anomaly_streak + 1,
-            np.where(ran, 0, self.anomaly_streak))
+        with tracing.span(tracing.PLACE):
+            loss_mult = (jnp.ones((B,), jnp.float32) if loss_mult is None
+                         else jnp.asarray(loss_mult, jnp.float32))
+            active = (jnp.ones((B,), jnp.float32) if active is None
+                      else jnp.asarray(active, jnp.float32))
+            if self.mesh is not None:
+                specs = rules.block_state_specs()
+                tokens = jax.device_put(
+                    tokens, NamedSharding(self.mesh, specs["tokens"]))
+                sp = NamedSharding(self.mesh, specs["stacked"])
+                loss_mult = jax.device_put(loss_mult, sp)
+                active = jax.device_put(active, sp)
+        with tracing.span(tracing.DISPATCH):
+            (stacks, stack_opt, periph, periph_opt, losses, gnorms, oks,
+             ewma) = self._step_fn(
+                state.stacks, state.stack_opt, state.periph,
+                state.periph_opt, tokens, rngs, self.qranges,
+                self.block_ids, loss_mult, active, self.guard_ewma,
+                jnp.asarray(bool(update_periphery)))
+        with tracing.span(tracing.GUARD_SYNC):
+            self.guard_ewma = ewma
+            oks_np = np.asarray(oks).astype(bool)
+            ran = np.asarray(active) > 0
+            bad = ran & ~oks_np
+            self.last_ok = oks_np | ~ran
+            self.anomalies += bad
+            self.anomaly_streak = np.where(
+                bad, self.anomaly_streak + 1,
+                np.where(ran, 0, self.anomaly_streak))
         return (BlockParallelState(stacks, periph, stack_opt, periph_opt),
                 losses, gnorms)
 
