@@ -51,6 +51,8 @@ def check_grads(f_ker, f_ref, args, tol, argnums=None):
     (1, 2, 2, 64, 64, 32),
     (2, 4, 2, 128, 128, 32),     # GQA: dk/dv group-sum path
     (1, 4, 1, 91, 175, 32),      # MQA, odd/ragged (padding path)
+    (1, 4, 2, 192, 192, 32),     # GQA; causal skips tiles, full and partial
+    (2, 2, 2, 200, 180, 32),     # Sq > Sk, both padded: a partial tail
 ])
 @pytest.mark.parametrize("causal,window", [(True, None), (True, 32),
                                            (False, None)])
@@ -72,15 +74,22 @@ def test_flash_attention_grads(B, H, KV, Sq, Sk, hd, dtype, causal, window):
     check_grads(f_ker, f_ref, (q, k, v), gtol(dtype))
 
 
-@pytest.mark.parametrize("mask_kind", ["db_concat", "two_pass"])
-def test_flash_attention_db_mask_grads(mask_kind):
+@pytest.mark.parametrize("mask_kind,S,block,H,KV", [
+    pytest.param("db_concat", 48, 32, 2, 2, id="db_concat"),
+    pytest.param("two_pass", 48, 32, 2, 2, id="two_pass"),
+    # the schedule skips tiles and runs full and partial ones; S = 100
+    # straddles a tile; GQA
+    ("db_concat", 100, 32, 4, 2), ("db_concat", 96, 64, 4, 1),
+    ("two_pass", 100, 32, 4, 1), ("two_pass", 96, 64, 4, 2),
+])
+def test_flash_attention_db_mask_grads(mask_kind, S, block, H, KV):
     """The DB training masks (App. E.4 concat / two-pass noisy stream)."""
-    S, hd = 48, 16
+    hd = 16
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(1), 3)
     Sq = 2 * S if mask_kind == "db_concat" else S
-    q = jax.random.normal(k1, (1, 2, Sq, hd))
-    k = jax.random.normal(k2, (1, 2, 2 * S, hd))
-    v = jax.random.normal(k3, (1, 2, 2 * S, hd))
+    q = jax.random.normal(k1, (1, H, Sq, hd))
+    k = jax.random.normal(k2, (1, KV, 2 * S, hd))
+    v = jax.random.normal(k3, (1, KV, 2 * S, hd))
     if mask_kind == "db_concat":
         from repro.nn.attention import db_concat_mask
         mask = db_concat_mask(S)(jnp.arange(2 * S), jnp.arange(2 * S))
@@ -90,8 +99,8 @@ def test_flash_attention_db_mask_grads(mask_kind):
 
     def f_ker(q, k, v):
         return jnp.sum(flash_attention(q, k, v, mask_kind=mask_kind,
-                                       mask_seq=S, block_q=32, block_k=32,
-                                       interpret=True) ** 2)
+                                       mask_seq=S, block_q=block,
+                                       block_k=block, interpret=True) ** 2)
 
     def f_ref(q, k, v):
         return jnp.sum(ref.mha_reference_masked(q, k, v, mask) ** 2)
@@ -142,6 +151,45 @@ def test_flash_attention_no_pallas_autodiff():
                        "flash_attention_bwd_dkv"}, kernels
     g = jax.grad(f)(q)
     assert np.isfinite(np.asarray(g)).all()
+
+
+def test_flash_attention_grid_is_the_schedule():
+    """The kernels run the schedule, not the dense grid: in the gradient's
+    jaxpr of a db_concat call, each of the three pallas_calls has the grid
+    (B, H, n_live) with n_live the length of ``tile_schedule``'s table."""
+    from jax.extend import core as jcore
+    from repro.kernels import flash_attention as FA
+    B, H, KV, S, hd, blk = 2, 4, 2, 100, 16, 32
+    q = jax.random.normal(jax.random.PRNGKey(0), (B, H, 2 * S, hd))
+    kv = jax.random.normal(jax.random.PRNGKey(1), (B, KV, 2 * S, hd))
+
+    def f(q, kv):
+        return jnp.sum(flash_attention(q, kv, kv, mask_kind="db_concat",
+                                       mask_seq=S, block_q=blk, block_k=blk,
+                                       interpret=True))
+
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = eqn.params["grid_mapping"].grid
+            for v in eqn.params.values():
+                for x in v if isinstance(v, (tuple, list)) else (v,):
+                    if isinstance(x, jcore.ClosedJaxpr):
+                        walk(x.jaxpr)
+                    elif isinstance(x, jcore.Jaxpr):
+                        walk(x)
+
+    walk(jax.make_jaxpr(jax.grad(f, argnums=(0, 1)))(q, kv).jaxpr)
+    cfg = FA.FlashConfig(mask_kind="db_concat", mask_seq=S, block_q=blk,
+                         block_k=blk)
+    (q_major, k_major), share = FA.tile_schedule(cfg, 2 * S, 2 * S)
+    n = -(-2 * S // blk)
+    assert q_major.shape[1] < n * n and share < 0.5
+    assert grids == {"flash_attention_fwd": (B, H, q_major.shape[1]),
+                     "flash_attention_bwd_dq": (B, H, q_major.shape[1]),
+                     "flash_attention_bwd_dkv": (B, H, k_major.shape[1])}
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.edm_loss import edm_loss
+from repro.kernels import flash_attention as FA
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import combine_self, flash_decode
 from repro.kernels.fused_adaln import (fused_euler, fused_gate_residual,
@@ -27,6 +28,8 @@ def tol(dtype):
     (2, 4, 2, 128, 128, 64),     # GQA
     (1, 4, 1, 96, 200, 32),      # MQA, ragged (padding path)
     (2, 2, 2, 256, 256, 128),    # MXU-aligned
+    (1, 4, 2, 192, 192, 32),     # GQA; causal skips tiles, full and partial
+    (2, 2, 1, 200, 180, 32),     # Sq > Sk, both padded: a partial tail
 ])
 @pytest.mark.parametrize("causal,window", [(True, None), (True, 32),
                                            (False, None)])
@@ -42,6 +45,88 @@ def test_flash_attention_sweep(B, H, KV, Sq, Sk, hd, dtype, causal, window):
     expect = ref.mha_reference(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), **tol(dtype))
+
+
+def _keep_mask(kind, Sq, Sk, mask_seq, window):
+    """The (Sq, Sk) keep-mask of the model's own mask constructors (an
+    independent definition from the kernel's ``_tile_mask``)."""
+    from repro.models.common import two_pass_mask
+    from repro.nn import attention as A
+    mod = (A.bidirectional_mask if kind == "full"
+           else A.causal_mask if kind == "causal"
+           else A.sliding_window_mask(window) if kind == "window"
+           else A.db_concat_mask(mask_seq) if kind == "db_concat"
+           else two_pass_mask(mask_seq))
+    return np.asarray(mod(jnp.arange(Sq), jnp.arange(Sk)), bool)
+
+
+def _check_order(table, state, outer):
+    """``table`` (rows q tile, k tile, flags) runs the live tiles of
+    ``state`` (nq, nk: 0 dead, 1 partial, 2 full) grouped by the tile of
+    row ``outer`` in increasing order, inner tiles increasing, and gives an
+    outer tile no live tile one EMPTY entry."""
+    inner = 1 - outer
+    st = state if outer == 0 else state.T
+    pos = 0
+    for o in range(st.shape[0]):
+        live = np.flatnonzero(st[o])
+        n = max(live.size, 1)
+        group = table[:, pos:pos + n]
+        pos += n
+        assert (group[outer] == o).all()
+        flags = group[2]
+        assert (flags & FA.FIRST).tolist() == [FA.FIRST] + [0] * (n - 1)
+        assert (flags & FA.LAST).tolist() == [0] * (n - 1) + [FA.LAST]
+        if live.size == 0:
+            assert flags[0] & FA.EMPTY and not flags[0] & FA.PARTIAL
+            assert 0 <= group[inner, 0] < st.shape[1]
+            continue
+        assert group[inner].tolist() == live.tolist()
+        assert not (flags & FA.EMPTY).any()
+        partial = (flags & FA.PARTIAL) != 0
+        assert partial.tolist() == (st[o, live] == 1).tolist()
+    assert pos == table.shape[1]
+
+
+@pytest.mark.parametrize("kind,Sq,Sk,mask_seq,window,bq,bk", [
+    ("full", 100, 96, None, None, 32, 32),        # padded q tail
+    ("causal", 96, 200, None, None, 32, 32),      # k tiles no q reaches
+    ("causal", 100, 100, None, None, 32, 64),     # rectangular tiles
+    ("window", 130, 130, None, 80, 32, 32),
+    ("db_concat", 200, 200, 100, None, 32, 32),   # S straddles a tile
+    ("db_concat", 2048, 2048, 1024, None, 128, 128),  # the LM's shape
+    ("two_pass", 100, 200, 100, None, 32, 64),
+])
+def test_flash_tile_schedule(kind, Sq, Sk, mask_seq, window, bq, bk):
+    """The block-sparse schedule runs exactly the tiles in which the mask
+    keeps some pair (a tile kept whole runs unmasked), every q tile in the
+    q-major table and every k tile in the k-major one."""
+    cfg = FA.FlashConfig(mask_kind=kind, window=window, mask_seq=mask_seq,
+                         block_q=bq, block_k=bk)
+    (q_major, k_major), share = FA.tile_schedule(cfg, Sq, Sk)
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    keep = np.zeros((nq * bq, nk * bk), bool)
+    keep[:Sq, :Sk] = _keep_mask(kind, Sq, Sk, mask_seq, window)
+    tiles = keep.reshape(nq, bq, nk, bk)
+    state = np.where(tiles.all(axis=(1, 3)), 2,
+                     tiles.any(axis=(1, 3)).astype(int))
+    assert (state == 2).any() and (state == 1).any()
+    assert (state == 0).any() == (kind != "full")
+    _check_order(q_major, state, outer=0)
+    _check_order(k_major, state, outer=1)
+    assert share == np.count_nonzero(state) / state.size
+    if kind == "db_concat" and Sq == 2048:
+        assert share == 80 / 256
+        assert q_major.shape[1] == k_major.shape[1] == 80
+        assert ((q_major[2] & FA.PARTIAL) != 0).sum() == 24
+
+
+@pytest.mark.parametrize("seq,tile", [(2048, 512), (1024, 512), (768, 256),
+                                      (384, 128), (197, 128), (64, 128)])
+def test_flash_default_tile(seq, tile):
+    """Tiles default to the longest of 512, 256, 128 dividing the sequence
+    (else 128 over a padded tail; ``_fit`` clips it to a shorter one)."""
+    assert FA.default_tile(seq) == tile
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
